@@ -53,8 +53,6 @@ def run_scenarios(which: str, bench_json: str = "BENCH_sweep.json",
     journal lives) reuses every chunk the interrupted run already spooled
     and recomputes only the missing/corrupt rest — the merged results are
     bit-identical to an uninterrupted run (see `exec.resume`)."""
-    import contextlib
-    import os
     import tempfile
 
     import jax
@@ -65,22 +63,6 @@ def run_scenarios(which: str, bench_json: str = "BENCH_sweep.json",
     from repro.sim import engine, phases, scenarios
     from repro.sim import exec as exec_
     from repro.sim.exec import dispatch
-
-    @contextlib.contextmanager
-    def forced_impl(impl: str):
-        """Route every lane through one decision path for the duration
-        (REPRO_KERNEL overrides ProtoConfig.kernel_impl in resolve_impl)."""
-        prev = os.environ.get(kernel_ops.ENV_IMPL)
-        if impl:
-            os.environ[kernel_ops.ENV_IMPL] = impl
-        try:
-            yield
-        finally:
-            if impl:
-                if prev is None:
-                    os.environ.pop(kernel_ops.ENV_IMPL, None)
-                else:
-                    os.environ[kernel_ops.ENV_IMPL] = prev
 
     def timing_since(tmark: int) -> dict:
         """Aggregate dispatch.TIMING_LOG entries appended since `tmark`,
@@ -123,7 +105,7 @@ def run_scenarios(which: str, bench_json: str = "BENCH_sweep.json",
         before = engine.trace_count()
         mark = dispatch.ACTIVE_LOG.mark()
         tmark = dispatch.TIMING_LOG.mark()
-        with forced_impl(kernel_impl):
+        with kernel_ops.forced(kernel_impl):
             results = run_scenario(name, store=use_store,
                                    early_exit=early_exit, resume=resume,
                                    **overrides)
@@ -167,7 +149,7 @@ def run_scenarios(which: str, bench_json: str = "BENCH_sweep.json",
                 tmark2 = dispatch.TIMING_LOG.mark()
                 print(f"# --- {name} kernel_impl={alt} pass ---",
                       flush=True)
-                with forced_impl(alt):
+                with kernel_ops.forced(alt):
                     run_scenario(name, early_exit=early_exit, **overrides)
                 kernel_timing.update(timing_since(tmark2))
         extras["kernel_impl"] = kernel_timing
@@ -265,8 +247,11 @@ def main() -> None:
     if isinstance(args.resume, str) and not args.scenario:
         args.scenario = args.resume
 
+    from . import common  # noqa: F401  (sys.path setup for repro)
+    from repro import compile_cache
+    compile_cache.enable()
+
     if args.list_scenarios:
-        from . import common  # noqa: F401  (sys.path setup for repro)
         from repro.sim import scenarios
         for n in scenarios.names():
             print(f"{n}: {scenarios.get(n).description}")

@@ -8,6 +8,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# CI runs on the CPU (Pallas kernels in interpret mode); a TPU is driven
+# by chip_smoke.py, never from here.
+export JAX_PLATFORMS=cpu
 
 # The exec-layer tests run in their own pytest process with 4 simulated
 # host devices so the multi-device sharded dispatch path is exercised on

@@ -1,6 +1,7 @@
-"""The BFC control law (§3.3.2), factored out so that the packet simulator,
-the pipeline-parallel scheduler, the serving admission controller and the data
-pipeline all share one implementation.
+"""The BFC control law (§3.3.2) in abstract units, shared by the
+pipeline-parallel scheduler, the serving admission controller and the data
+pipeline. The packet simulator runs at mu = 1 packet per tick and uses the
+integer form of the same threshold, `kernels.bfc_step.ref.pause_threshold`.
 
 Everything is expressed in abstract units:
   * ``hrtt``       -- one hop round-trip (ticks / seconds / scheduler steps)

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import BIG, packed_sentinel
+from .ref import BIG, packed_sentinel, pause_threshold
 
 
 def _pad_ports(p: int, block_p: int, *rows):
@@ -59,7 +59,7 @@ def _kernel(occ_ref, qpaused_ref, ptr_ref, o_nact, o_th, o_pause, o_sel, *,
     active = (occ > 0) & jnp.logical_not(qpaused)
     n_act = jnp.maximum(jnp.sum(active.astype(jnp.int32), axis=1,
                                 keepdims=True), 1)
-    th = (pause_window + n_act - 1) // n_act    # ceil, >= 1
+    th = pause_threshold(n_act, pause_window)
     o_nact[...] = n_act
     o_th[...] = th
     o_pause[...] = occ > th
@@ -102,7 +102,7 @@ def bfc_decide(occ, qpaused, ptr, *, pause_window: int, block_p: int = 256,
             jax.ShapeDtypeStruct((pp, q), jnp.bool_),
             jax.ShapeDtypeStruct((pp, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(occ, qpaused, ptr[:, None])
@@ -122,7 +122,7 @@ def _fused_kernel(occ_ref, qpaused_ref, ptr_ref, blocked_ref, *refs,
     active = (occ > 0) & jnp.logical_not(qpaused)
     n_act = jnp.maximum(jnp.sum(active.astype(jnp.int32), axis=1,
                                 keepdims=True), 1)
-    th = (pause_window + n_act - 1) // n_act    # ceil, >= 1
+    th = pause_threshold(n_act, pause_window)
     o_nact[...] = n_act
     o_th[...] = th
     o_pause[...] = occ > th
@@ -187,7 +187,7 @@ def bfc_fused(occ, qpaused, ptr, blocked, *, pause_window: int,
             jax.ShapeDtypeStruct((pp, 1), jnp.bool_),
             jax.ShapeDtypeStruct((pp, q), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*inputs)

@@ -23,6 +23,7 @@ stale jit cache keyed on ``"auto"``.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -57,6 +58,24 @@ def resolve_impl(impl: str = "auto", *, lax_name: str = "ref") -> str:
             return "interpret"
         return lax_name
     return impl
+
+
+@contextlib.contextmanager
+def forced(impl: str):
+    """Route every switch decision through `impl` for the duration: sets
+    ``REPRO_KERNEL``, which overrides `ProtoConfig.kernel_impl` in
+    `resolve_impl`. An empty `impl` changes nothing."""
+    prev = os.environ.get(ENV_IMPL)
+    if impl:
+        os.environ[ENV_IMPL] = impl
+    try:
+        yield
+    finally:
+        if impl:
+            if prev is None:
+                os.environ.pop(ENV_IMPL, None)
+            else:
+                os.environ[ENV_IMPL] = prev
 
 
 @functools.partial(jax.jit, static_argnames=("pause_window", "impl",

@@ -25,12 +25,22 @@ def packed_sentinel(nq: int, max_key: int) -> int:
     return sentinel
 
 
+def pause_threshold(n_active, pause_window: int):
+    """BFC's per-port pause threshold ceil(pause_window / n_active)
+    (paper §3.3.2) in integer arithmetic; >= 1 since n_active >= 1 and
+    pause_window >= 1. The one copy of this law: the Pallas kernel, this
+    oracle and the engine's lax path all call it, so the two decision
+    paths cannot round differently on any backend (a float32 quotient
+    need not be correctly rounded on every chip)."""
+    return (pause_window + n_active - 1) // n_active
+
+
 def bfc_decide_ref(occ, qpaused, ptr, *, pause_window: int):
     p, q = occ.shape
     sentinel = packed_sentinel(q, q - 1)
     active = (occ > 0) & ~qpaused
     n_act = jnp.maximum(active.sum(axis=1), 1)
-    th = (pause_window + n_act - 1) // n_act
+    th = pause_threshold(n_act, pause_window)
     pause = occ > th[:, None]
     q_ix = jnp.arange(q)[None, :]
     drr_key = (q_ix - ptr[:, None]) % q
@@ -48,7 +58,7 @@ def bfc_fused_ref(occ, qpaused, ptr, blocked, *, pause_window: int,
     p, q = occ.shape
     active = (occ > 0) & ~qpaused
     n_act = jnp.maximum(active.sum(axis=1), 1)
-    th = (pause_window + n_act - 1) // n_act
+    th = pause_threshold(n_act, pause_window)
     pause = occ > th[:, None]
     q_ix = jnp.arange(q, dtype=jnp.int32)[None, :]
     if scheduler == "srf":

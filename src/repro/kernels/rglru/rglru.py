@@ -84,7 +84,7 @@ def rglru_scan(log_a, b, h0, *, chunk: int = 128, block_w: int = 128,
             jax.ShapeDtypeStruct((bsz, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(log_a, b, h0)

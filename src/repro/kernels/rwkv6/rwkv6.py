@@ -94,7 +94,7 @@ def wkv(r, k, v, logw, u, h0, *, chunk: int = 16, interpret: bool = False):
             jax.ShapeDtypeStruct((b, h, dd, dd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dd, dd), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(r, k, v, logw, u, h0)

@@ -41,6 +41,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec
 
 from ..core import bloom
 from ..core.flow_table import FlowTableParams, buckets_of
@@ -363,7 +364,8 @@ def _finish_tail(env, st: SimState, emits, topo_ops, n_ticks: int,
 
 def compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
                     n_ticks: int, unroll: int = 1, batched: bool = False,
-                    segment: int = DEFAULT_SEGMENT, early_exit: bool = True):
+                    segment: int = DEFAULT_SEGMENT, early_exit: bool = True,
+                    devices=None):
     """The jitted simulator program for one static signature.
 
     Keyed on everything that shapes the XLA program: `TopoDims`, the
@@ -378,15 +380,23 @@ def compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
     segmented while-loop then runs until every lane is quiescent, masking
     finished lanes). Returns `(state, emits[T, 3 + trace], active_ticks)` —
     `active_ticks` is the tick the run actually simulated to before the
-    closed-form tail took over (= n_ticks when no early exit)."""
+    closed-form tail took over (= n_ticks when no early exit).
+
+    With several `devices` (and `batched=True`) the batch axis is split
+    over a one-axis ``lanes`` mesh of them by `shard_map`, so each device
+    loops over its own lanes only. Lanes share nothing, so this is
+    bit-identical to one device; and XLA cannot partition a Pallas (Mosaic)
+    kernel by itself, so it is what lets the kernel path run on several
+    chips."""
     return _compiled_runner(dims, static_cfg(cfg), n_flows, n_ticks,
-                            unroll, batched, segment, early_exit)
+                            unroll, batched, segment, early_exit,
+                            tuple(devices) if devices else None)
 
 
 @functools.lru_cache(maxsize=None)
 def _compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
                      n_ticks: int, unroll: int, batched: bool,
-                     segment: int, early_exit: bool):
+                     segment: int, early_exit: bool, devices):
     init_state, step = make_step(dims, cfg, n_flows)
     env = phases.make_env(dims, cfg, n_flows)
     # emit row width: 3 legacy columns + the opt-in trace channels
@@ -439,6 +449,14 @@ def _compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
         return (jax.vmap(one)(flow_ops, topo_ops) if batched
                 else one(flow_ops, topo_ops))
 
+    if devices is not None and len(devices) > 1:
+        if not batched:
+            raise ValueError("only a batched runner splits over devices")
+        lanes = PartitionSpec("lanes")
+        # every value is per-lane; the carry starts from constants, which
+        # the varying-axis check would otherwise reject
+        go = jax.shard_map(go, mesh=Mesh(np.asarray(devices), ("lanes",)),
+                           in_specs=lanes, out_specs=lanes, check_vma=False)
     return jax.jit(go)
 
 

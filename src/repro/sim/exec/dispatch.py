@@ -2,12 +2,12 @@
 compiled executable.
 
 Lanes of each chunk are sharded evenly across the plan's devices with a
-batch-axis `NamedSharding` — SPMD partitioning of the ONE cached vmapped
-program, not per-device jits, so the compile-count contract ("one XLA
-compilation per protocol variant", `engine.trace_count`) survives
-multi-device execution. Per-lane computation is independent (the vmap axis
-carries no collectives), so a sharded run is bit-identical to the serial
-single-device run.
+batch-axis `NamedSharding`, and the ONE cached vmapped program is split
+over them by `shard_map` (see `engine.compiled_runner`) — not per-device
+jits, so the compile-count contract ("one XLA compilation per protocol
+variant", `engine.trace_count`) survives multi-device execution. Each
+device loops over its own lanes and no collective crosses devices, so a
+sharded run is bit-identical to the serial single-device run.
 
 Chunks are double-buffered: chunk i+1 is dispatched (JAX dispatch is
 async) before chunk i is pulled back to host, so `jax.device_get` +
@@ -89,7 +89,10 @@ ACTIVE_LOG: BoundedLog = BoundedLog(ACTIVE_LOG_MAX)
 # covers dispatch through landing (compile included on the first call for
 # a config — take a warmup run first when isolating steady-state cost);
 # `tick_wall_us` divides by the total ACTIVE ticks actually simulated, so
-# quiescence early exit does not flatter either path.
+# quiescence early exit does not flatter either path. `budget_source` and
+# `devices` repeat the plan's; `out_devices` is the most devices one
+# computed chunk's outputs spanned (0 when every chunk was reloaded or
+# landed through the retry path).
 LAST_TIMING: Optional[Dict] = None
 TIMING_LOG: BoundedLog = BoundedLog(ACTIVE_LOG_MAX)
 
@@ -198,10 +201,13 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
         raise ValueError(f"chunk width {W} not a multiple of "
                          f"{plan.n_devices} devices")
 
-    go = engine.compiled_runner(plan.dims, engine.static_cfg(cfg),
-                                plan.f_max, plan.n_ticks, plan.unroll,
-                                batched=True, segment=plan.segment,
-                                early_exit=plan.early_exit)
+    def runner(devices=None):
+        return engine.compiled_runner(
+            plan.dims, engine.static_cfg(cfg), plan.f_max, plan.n_ticks,
+            plan.unroll, batched=True, segment=plan.segment,
+            early_exit=plan.early_exit, devices=devices)
+
+    go = runner(plan.devices if plan.sharded else None)
     sharding = lane_sharding(plan.devices) if plan.sharded else None
     # trace channels ride the emit rows (see sim/trace/): split them off
     # at landing so callers keep the (K, T, 3) emits contract, spool them
@@ -218,6 +224,7 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
 
     n_retries = 0
     n_reused = 0
+    out_devices = 0     # most devices one landed chunk's outputs spanned
 
     def _stack(lo: int, n_take: int, width: int):
         """Operand bundles for lanes [lo, lo+n_take), padded to `width`
@@ -263,7 +270,7 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
             n_take = min(w, n_real - off)
             try:
                 faults.fire("chunk", idx)
-                st, em, ac = _land(*go(*_stack(lo + off, n_take, w)),
+                st, em, ac = _land(*runner()(*_stack(lo + off, n_take, w)),
                                    n_take)
             except Exception as err2:     # noqa: BLE001 — filtered below
                 if not faults.is_oom(err2):
@@ -365,8 +372,10 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
             chunks.append((st, emits))
 
     def land_oldest():
+        nonlocal out_devices
         idx, lo, kind, n_real, st, emits, active = inflight.popleft()
         if kind == "inflight":
+            out_devices = max(out_devices, len(emits.sharding.device_set))
             try:
                 st, emits, active = _land(st, emits, active, n_real)
             except Exception as err:      # noqa: BLE001 — filtered below
@@ -415,6 +424,9 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
         "tick_wall_us": wall_s * 1e6 / max(active_total, 1),
         "retries": n_retries,
         "chunks_reused": n_reused,
+        "budget_source": plan.budget_source,
+        "devices": plan.n_devices,
+        "out_devices": out_devices,
     }
     TIMING_LOG.append(LAST_TIMING)
 

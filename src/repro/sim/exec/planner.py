@@ -14,11 +14,12 @@ reports which as `ExecPlan.budget_source`):
 3. ``memory_stats`` — accelerators report ``device.memory_stats()``
    (``bytes_limit`` - ``bytes_in_use``): chunks shard *evenly*, so the
    budget is min-free x device count — the least-free device binds the
-   whole set;
+   whole set. An accelerator that reports no limit is an error;
 4. ``host_meminfo`` — host-platform devices (CPU, incl.
    ``xla_force_host_platform_device_count`` splits) are slices of one RAM
    pool, read from ``/proc/meminfo`` MemAvailable;
-5. ``uncapped`` — nothing readable: the whole grid in one dispatch.
+5. ``uncapped`` — CPU devices on a host with no readable MemAvailable:
+   the whole grid in one dispatch.
 
 A fraction (`DEFAULT_MEM_FRACTION`, 0.8) of the readable figure is
 budgeted so compiler scratch and host buffers keep headroom.
@@ -95,18 +96,16 @@ def host_available_bytes(path: str = MEMINFO_PATH) -> Optional[int]:
     return None
 
 
-def device_free_bytes(dev) -> Optional[int]:
-    """Free bytes a device reports via memory_stats(), or None (CPU devices
-    report no stats; their budget comes from host RAM instead)."""
-    try:
-        stats = dev.memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
+def device_free_bytes(dev) -> int:
+    """Free bytes an accelerator reports via memory_stats(). A device that
+    cannot report them is an error: budgeting it from host RAM would
+    hide which device the run is on."""
+    stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit", stats.get("bytes_reservable_limit"))
     if limit is None:
-        return None
+        raise RuntimeError(
+            f"{dev.platform} device {dev} reports no memory limit in "
+            f"memory_stats() ({stats!r}); pass an explicit budget")
     return max(0, int(limit) - int(stats.get("bytes_in_use", 0)))
 
 
@@ -117,21 +116,22 @@ def auto_budget_bytes(devices: Sequence,
                       ) -> Tuple[Optional[int], str]:
     """(total device-resident byte budget, source) for a device set.
 
-    Source is one of 'env', 'memory_stats', 'host_meminfo', 'uncapped'."""
+    Source is one of 'env', 'memory_stats', 'host_meminfo', 'uncapped';
+    only CPU devices take 'host_meminfo' or 'uncapped'."""
     env_val = os.environ.get(env)
     if env_val:
         return int(env_val), "env"
-    free = [device_free_bytes(d) for d in devices]
-    if free and all(f is not None for f in free):
-        # chunks shard EVENLY across devices, so the least-free device is
-        # the binding constraint — min * n, not sum (a lopsided pair would
-        # otherwise OOM the small device)
-        return int(min(free) * len(free) * fraction), "memory_stats"
-    host = host_available_bytes(meminfo)
-    if host is not None:
+    if all(d.platform == "cpu" for d in devices):
         # host-platform devices are slices of one RAM pool: budget the pool
+        host = host_available_bytes(meminfo)
+        if host is None:
+            return None, "uncapped"
         return int(host * fraction), "host_meminfo"
-    return None, "uncapped"
+    # chunks shard EVENLY across devices, so the least-free device is the
+    # binding constraint — min * n, not sum (a lopsided pair would
+    # otherwise OOM the small device)
+    free = [device_free_bytes(d) for d in devices]
+    return int(min(free) * len(free) * fraction), "memory_stats"
 
 
 @dataclass(frozen=True)

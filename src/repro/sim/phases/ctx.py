@@ -15,6 +15,7 @@ import numpy as np
 
 from ...core import bloom
 from ...kernels.bfc_step import ops as kernel_ops
+from ...kernels.bfc_step.ref import pause_threshold
 from ..config import SimConfig
 from ..topology import MAX_HOPS, TopoDims
 
@@ -310,9 +311,7 @@ def derive(env: PhaseEnv, st, ops, topo) -> StepCtx:
         qpaused = jnp.zeros((P, Q), bool)
 
     n_active = jnp.maximum(((occ > 0) & ~qpaused).sum(axis=1), 1)  # (P,)
-    th = jnp.maximum(
-        jnp.ceil(tm.pause_window / n_active.astype(jnp.float32)), 1.0
-    ).astype(I32)                                                  # (P,)
+    th = pause_threshold(n_active, tm.pause_window)                # (P,)
 
     # PFC state (hysteresis: pause above th, resume below th/2)
     if pc.pfc:

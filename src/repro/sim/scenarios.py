@@ -218,7 +218,8 @@ def run(name_or_scenario, clos: Optional[ClosParams] = None,
         unroll: int = 1, max_batch_bytes: Optional[int] = None,
         devices: Optional[Sequence] = None, auto_budget: bool = True,
         store=None, early_exit: bool = True, resume: bool = False,
-        long_lived_pkts: Optional[int] = None, trace=None):
+        long_lived_pkts: Optional[int] = None, trace=None,
+        n_ticks: Optional[int] = None):
     """Run one registry scenario through the batched sweep subsystem.
 
     `clos` sets the fabric for scenarios without their own `topologies`
@@ -233,7 +234,9 @@ def run(name_or_scenario, clos: Optional[ClosParams] = None,
     of `table1_long_lived` use it so the probe flow can complete and the
     drain tail goes quiescent). A `trace` TraceSpec turns on per-tick
     channel capture for every case of the grid (spooled per segment when
-    a `store` is given; see sim/trace/). Returns a list of
+    a `store` is given; see sim/trace/). `n_ticks` cuts every lane's
+    simulated horizon to that many ticks (default: the grid's largest
+    flow horizon plus the drain). Returns a list of
     sweep.CaseResult (one per grid point), each carrying per-config
     SimState, emits, and summarized RunMetrics. Grids containing the
     centralized oracle get every lane's metrics annotated with
@@ -247,7 +250,7 @@ def run(name_or_scenario, clos: Optional[ClosParams] = None,
     if trace is not None:
         cases = [(label, replace(cfg, trace=trace), fl)
                  for label, cfg, fl in cases]
-    results = sweep.run_grid(topo, cases,
+    results = sweep.run_grid(topo, cases, n_ticks=n_ticks,
                              drain=(drain if drain is not None
                                     else sc.drain_ticks),
                              unroll=unroll, max_batch_bytes=max_batch_bytes,

@@ -112,8 +112,9 @@ def test_auto_budget_source_fallbacks(cfg, monkeypatch, tmp_path):
     monkeypatch.delenv(exec_.ENV_BUDGET, raising=False)
 
     class Dev:
-        def __init__(self, stats):
+        def __init__(self, stats, platform="tpu"):
             self._stats = stats
+            self.platform = platform
 
         def memory_stats(self):
             return self._stats
@@ -125,16 +126,21 @@ def test_auto_budget_source_fallbacks(cfg, monkeypatch, tmp_path):
     budget, source = exec_.auto_budget_bytes(devs, fraction=1.0)
     assert (budget, source) == (500 * 2, "memory_stats")
 
-    # CPU-style devices (no stats) fall back to host MemAvailable
+    # an accelerator that reports no stats is an error, never host RAM
     meminfo = tmp_path / "meminfo"
     meminfo.write_text("MemTotal:  200 kB\nMemAvailable:  100 kB\n")
-    budget, source = exec_.auto_budget_bytes([Dev(None)], fraction=0.5,
+    with pytest.raises(RuntimeError, match="reports no memory limit"):
+        exec_.auto_budget_bytes([Dev(None)], meminfo=str(meminfo))
+
+    # CPU devices (no stats) budget the host's MemAvailable
+    budget, source = exec_.auto_budget_bytes([Dev(None, "cpu")],
+                                             fraction=0.5,
                                              meminfo=str(meminfo))
     assert (budget, source) == (100 * 1024 // 2, "host_meminfo")
 
     # nothing readable -> uncapped
     budget, source = exec_.auto_budget_bytes(
-        [Dev(None)], meminfo=str(tmp_path / "missing"))
+        [Dev(None, "cpu")], meminfo=str(tmp_path / "missing"))
     assert (budget, source) == (None, "uncapped")
 
 
