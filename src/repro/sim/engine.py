@@ -18,7 +18,7 @@ quiescent suffix is reconstructed in closed form (`_finish_tail`) — the
 final state and emits are bit-identical to the flat scan, which survives
 as the `early_exit=False` escape hatch for A/B runs. The runner returns
 `(state, emits, active_ticks)`; `active_ticks` (< n_ticks on early exit)
-feeds the exec layer's readback and the BENCH_sweep perf trajectory.
+is landed by the exec layer into `exec.last_active_ticks()`.
 
 This module owns the operand/state definitions and the compile cache; the
 per-tick work lives in the phase pipeline under `repro.sim.phases`
@@ -235,13 +235,23 @@ def make_step(dims: TopoDims, cfg: SimConfig, n_flows: int):
         )
 
     def step(st: SimState, ops: FlowOperands, topo_ops):
-        ctx = phases.derive(env, st, ops, topo_ops)
-        ctx = phases.control(env, st, ops, topo_ops, ctx)
-        ctx = phases.switch_tx(env, st, ops, topo_ops, ctx)
-        ctx = phases.nic_tx(env, st, ops, topo_ops, ctx)
-        ctx = phases.arrivals(env, st, ops, topo_ops, ctx)
-        ctx = phases.feedback(env, st, ops, topo_ops, ctx)
-        return phases.stats(env, st, ops, topo_ops, ctx)
+        # `phase.<name>` lands in each op's op_name metadata, so a profiler
+        # trace assigns device time to phases (docs/ARCHITECTURE.md,
+        # "Observability"); it changes no instruction
+        with jax.named_scope("phase.derive"):
+            ctx = phases.derive(env, st, ops, topo_ops)
+        with jax.named_scope("phase.control"):
+            ctx = phases.control(env, st, ops, topo_ops, ctx)
+        with jax.named_scope("phase.switch_tx"):
+            ctx = phases.switch_tx(env, st, ops, topo_ops, ctx)
+        with jax.named_scope("phase.nic_tx"):
+            ctx = phases.nic_tx(env, st, ops, topo_ops, ctx)
+        with jax.named_scope("phase.arrivals"):
+            ctx = phases.arrivals(env, st, ops, topo_ops, ctx)
+        with jax.named_scope("phase.feedback"):
+            ctx = phases.feedback(env, st, ops, topo_ops, ctx)
+        with jax.named_scope("phase.stats"):
+            return phases.stats(env, st, ops, topo_ops, ctx)
 
     return init_state, step
 
@@ -422,23 +432,28 @@ def _compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
             st, emits = carry
             t0 = st.t
             st, e = seg_scan(st, flow_ops, topo_ops, length)
-            return st, jax.lax.dynamic_update_slice(
-                emits, e, (t0, jnp.int32(0)))
+            with jax.named_scope("runner.emit_write"):
+                return st, jax.lax.dynamic_update_slice(
+                    emits, e, (t0, jnp.int32(0)))
+
+        def done(st):
+            with jax.named_scope("runner.quiescent"):
+                return quiescent(st, flow_ops)
 
         st, emits = jax.lax.while_loop(
-            lambda c: (c[0].t < n_full * seg)
-            & ~quiescent(c[0], flow_ops),
+            lambda c: (c[0].t < n_full * seg) & ~done(c[0]),
             lambda c: advance(c, seg),
             (init_state(), jnp.zeros((n_ticks, emit_w), I32)))
         if rem:
             # horizon not a segment multiple: run the remainder unless the
             # loop already went quiescent (then the tail covers it)
             st, emits = jax.lax.cond(
-                quiescent(st, flow_ops), lambda c: c,
+                done(st), lambda c: c,
                 lambda c: advance(c, rem), (st, emits))
         active = st.t
-        st, emits = _finish_tail(env, st, emits, topo_ops, n_ticks,
-                                 step=step, flow_ops=flow_ops)
+        with jax.named_scope("runner.tail"):
+            st, emits = _finish_tail(env, st, emits, topo_ops, n_ticks,
+                                     step=step, flow_ops=flow_ops)
         return st, emits, active
 
     one = one_flat if not early_exit or n_ticks == 0 else one_segmented

@@ -30,8 +30,8 @@ docs/ARCHITECTURE.md ("The execution layer", "Fault tolerance & resume").
 """
 from .dispatch import (ACTIVE_LOG, BoundedLog, RETRY_LOG,  # noqa: F401
                        TIMING_LOG, TRACE_LOG, execute, lane_sharding,
-                       last_active_ticks, last_plan, last_timing,
-                       last_trace, resume)
+                       last_active_ticks, last_plan, last_spans,
+                       last_timing, last_trace, resume, span)
 from .faults import (ENV_FAULTS, ExecError, FaultInjector,  # noqa: F401
                      FaultSpec, SimulatedCrash, SimulatedOOM)
 from .planner import (DEFAULT_MEM_FRACTION, DEFAULT_PIPELINE_DEPTH,  # noqa: F401

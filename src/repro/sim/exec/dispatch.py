@@ -18,11 +18,20 @@ synchronous, depth 2 = classic double buffer; the planner already divided
 the byte budget by this depth, see `exec.planner`). Tail chunks are
 padded with repeats of lane 0 so every dispatch reuses the one compiled
 program; padded lanes are dropped at landing.
+
+Each stage of a call (stack, shard, launch, wait, readback, spool, retry)
+is a `span`: a profiler annotation and an entry in the call's in-memory
+record, which `LAST_TIMING` is read from (docs/ARCHITECTURE.md,
+"Observability").
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
+import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -67,6 +76,73 @@ class BoundedLog(list):
         return list(self[max(0, mark - self._dropped):])
 
 
+@dataclass(eq=False)
+class Span:
+    """One host stage of a top-level call. `parent` is the index of the
+    enclosing span in the call's record (None for the call itself);
+    `dur_ns` is -1 while the span is open; `counts` are the work counts
+    the stage carries (lanes, bytes, ...)."""
+    name: str
+    parent: Optional[int]
+    start_ns: int
+    dur_ns: int = -1
+    counts: Dict[str, int] = field(default_factory=dict)
+
+
+# The open top-level call's span record and stack of open spans, and the
+# record of the most recent finished top-level call (one call, replaced
+# by the next: not a log).
+_OPEN = threading.local()
+_CALL_IDS = itertools.count(1)
+LAST_SPANS: List[Span] = []
+
+
+@contextlib.contextmanager
+def span(name: str, **counts: int):
+    """Time one stage of the current top-level call: a profiler
+    `TraceAnnotation(name, call=<id>, **counts)`, on the same clock as the
+    device trace, and a `Span` appended to the call's in-memory record.
+    The outermost span opens a new record and call id, which every span
+    inside it shares. Use per chunk or per case, never per tick or flow."""
+    global LAST_SPANS
+    if not getattr(_OPEN, "stack", None):
+        _OPEN.record, _OPEN.stack, _OPEN.call = [], [], next(_CALL_IDS)
+    record, stack = _OPEN.record, _OPEN.stack
+    sp = Span(name, stack[-1] if stack else None, time.perf_counter_ns(),
+              counts=counts)
+    stack.append(len(record))
+    record.append(sp)
+    try:
+        with jax.profiler.TraceAnnotation(name, call=_OPEN.call, **counts):
+            yield sp
+    finally:
+        sp.dur_ns = time.perf_counter_ns() - sp.start_ns
+        stack.pop()
+        if not stack:
+            LAST_SPANS = record
+
+
+def last_spans() -> List[Span]:
+    return LAST_SPANS
+
+
+def self_seconds(record: Sequence[Span], root: Span) -> Dict[str, float]:
+    """Seconds each span name spent outside its child spans, summed over
+    `root` and every span under it."""
+    top = next(i for i, sp in enumerate(record) if sp is root)
+    under = {top}
+    out: Dict[str, float] = defaultdict(float)
+    for i in range(top, len(record)):
+        sp = record[i]
+        if i != top and sp.parent not in under:
+            continue
+        under.add(i)
+        out[sp.name] += sp.dur_ns / 1e9
+        if i != top:
+            out[record[sp.parent].name] -= sp.dur_ns / 1e9
+    return dict(out)
+
+
 # The most recent plan `execute` ran — introspection hook for examples,
 # benchmarks, and trace_guard (what did the planner decide?).
 LAST_PLAN: Optional[ExecPlan] = None
@@ -84,15 +160,15 @@ ACTIVE_LOG: BoundedLog = BoundedLog(ACTIVE_LOG_MAX)
 
 # Wall-clock accounting of the most recent `execute` call, keyed by the
 # resolved `ProtoConfig.kernel_impl` so lax-vs-kernel benchmark runs can
-# report per-tick cost per decision path (`benchmarks.run` writes these
-# into BENCH_sweep.json's `kernel_impl` column). `wall_s`
-# covers dispatch through landing (compile included on the first call for
-# a config — take a warmup run first when isolating steady-state cost);
-# `tick_wall_us` divides by the total ACTIVE ticks actually simulated, so
-# quiescence early exit does not flatter either path. `budget_source` and
-# `devices` repeat the plan's; `out_devices` is the most devices one
-# computed chunk's outputs spanned (0 when every chunk was reloaded or
-# landed through the retry path).
+# report per-tick cost per decision path (`benchmarks.run` writes wall
+# time per ACTIVE tick into BENCH_sweep.json's `kernel_impl` column).
+# `wall_s` is the `repro.dispatch.execute` span: dispatch through landing
+# (compile included on the first call for a config — take a warmup run
+# first when isolating steady-state cost); `stages` splits it into the
+# self seconds of each `repro.dispatch.*` span name (see `span`).
+# `budget_source` and `devices` repeat the plan's; `out_devices` is the
+# most devices one computed chunk's outputs spanned (0 when every chunk
+# was reloaded or landed through the retry path).
 LAST_TIMING: Optional[Dict] = None
 TIMING_LOG: BoundedLog = BoundedLog(ACTIVE_LOG_MAX)
 
@@ -144,12 +220,18 @@ def _shard_tree(tree, sharding: NamedSharding):
 
 def _land(st, emits, active, n_real: int
           ) -> Tuple[SimState, np.ndarray, np.ndarray]:
-    """Pull one chunk to host and drop its padded lanes (blocks until the
-    device is done with this chunk — later chunks keep computing)."""
-    st = jax.device_get(st)
-    st = SimState(**{name: np.asarray(leaf)[:n_real]
-                     for name, leaf in st._asdict().items()})
-    return st, np.asarray(emits)[:n_real], np.asarray(active)[:n_real]
+    """Pull one chunk to host and drop its padded lanes: wait until the
+    device is done with this chunk (later chunks keep computing), then
+    copy it back and trim."""
+    out = (st, emits, active)
+    with span("repro.dispatch.wait"):
+        jax.block_until_ready(out)
+    n_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(out))
+    with span("repro.dispatch.readback", bytes=int(n_bytes)):
+        st, emits, active = jax.device_get(out)
+        st = SimState(**{name: np.asarray(leaf)[:n_real]
+                         for name, leaf in st._asdict().items()})
+        return st, np.asarray(emits)[:n_real], np.asarray(active)[:n_real]
 
 
 def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
@@ -182,7 +264,11 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
     this plan's lane ranges — are reloaded from disk instead of
     recomputed, and only the missing/corrupt remainder is dispatched; the
     merged result is bit-identical to a from-scratch run because lanes are
-    independent and the npz round-trip is exact."""
+    independent and the npz round-trip is exact.
+
+    Dispatch through landing is one `repro.dispatch.execute` span; its
+    stages (stack, shard, launch, wait, readback, spool, retry) are spans
+    under it, and their self seconds land in `LAST_TIMING["stages"]`."""
     global LAST_PLAN, LAST_ACTIVE, LAST_TIMING, LAST_TRACE
     LAST_PLAN = plan
     if not collect and store is None:
@@ -226,25 +312,30 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
     n_reused = 0
     out_devices = 0     # most devices one landed chunk's outputs spanned
 
-    def _stack(lo: int, n_take: int, width: int):
+    def _stack(idx: int, lo: int, n_take: int, width: int):
         """Operand bundles for lanes [lo, lo+n_take), padded to `width`
         with repeats of lane 0 (padded results dropped at landing)."""
-        fsets = list(flowsets[lo:lo + n_take])
-        fsets += [flowsets[0]] * (width - n_take)
-        tps = list(topos[lo:lo + n_take])
-        tps += [topos[0]] * (width - n_take)
-        return (sweep.stack_operands(fsets, cfg, plan.f_max),
-                sweep.stack_topos(tps, cfg, plan.dims))
+        with span("repro.dispatch.stack", lanes=n_take,
+                  padded_lanes=width - n_take, chunk=idx):
+            fsets = list(flowsets[lo:lo + n_take])
+            fsets += [flowsets[0]] * (width - n_take)
+            tps = list(topos[lo:lo + n_take])
+            tps += [topos[0]] * (width - n_take)
+            return (sweep.stack_operands(fsets, cfg, plan.f_max),
+                    sweep.stack_topos(tps, cfg, plan.dims))
 
-    def launch(lo: int, n_real: int):
+    def launch(idx: int, lo: int, n_real: int):
         """Stack + (optionally) shard one planned-width chunk and launch
         it (async). Tail chunks are padded so every dispatch reuses the
         one compiled program."""
-        ops, t_ops = _stack(lo, n_real, W)
+        ops, t_ops = _stack(idx, lo, n_real, W)
         if sharding is not None:
-            ops = _shard_tree(ops, sharding)
-            t_ops = _shard_tree(t_ops, sharding)
-        return go(ops, t_ops)
+            with span("repro.dispatch.shard", chunk=idx):
+                ops = _shard_tree(ops, sharding)
+                t_ops = _shard_tree(t_ops, sharding)
+        with span("repro.dispatch.launch", lanes=n_real,
+                  padded_lanes=W - n_real, chunk=idx):
+            return go(ops, t_ops)
 
     def retry_chunk(idx: int, lo: int, n_real: int,
                     err: BaseException) -> Tuple:
@@ -254,6 +345,11 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
         `plan.retry`'s budget. Returns the chunk landed to host; raises a
         structured `ExecError` naming the unlanded lanes when the budget
         is spent or width-1 still OOMs."""
+        with span("repro.dispatch.retry", lanes=n_real, chunk=idx):
+            return _retry_chunk(idx, lo, n_real, err)
+
+    def _retry_chunk(idx: int, lo: int, n_real: int,
+                     err: BaseException) -> Tuple:
         nonlocal n_retries
         pol = plan.retry
         w = max(pol.min_width, min(W, n_real) // 2)
@@ -270,8 +366,11 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
             n_take = min(w, n_real - off)
             try:
                 faults.fire("chunk", idx)
-                st, em, ac = _land(*runner()(*_stack(lo + off, n_take, w)),
-                                   n_take)
+                ops = _stack(idx, lo + off, n_take, w)
+                with span("repro.dispatch.launch", lanes=n_take,
+                          padded_lanes=w - n_take, chunk=idx):
+                    out = runner()(*ops)
+                st, em, ac = _land(*out, n_take)
             except Exception as err2:     # noqa: BLE001 — filtered below
                 if not faults.is_oom(err2):
                     raise
@@ -314,7 +413,7 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
         n_real = min(W, K - lo)
         try:
             faults.fire("chunk", idx)
-            return ("inflight", n_real) + tuple(launch(lo, n_real))
+            return ("inflight", n_real) + tuple(launch(idx, lo, n_real))
         except Exception as err:          # noqa: BLE001 — filtered below
             if not faults.is_oom(err):
                 raise
@@ -363,11 +462,12 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
         if lay.width:
             traces.append(trace)
         if spool and store is not None:
-            store.spool_chunk(tag, idx, st, emits, active_ticks=active,
-                              trace=trace if lay.width else None,
-                              trace_channels=lay.meta() if lay.width
-                              else None,
-                              run=resume_run, lane_lo=lo)
+            with span("repro.dispatch.spool", lanes=len(active), chunk=idx):
+                store.spool_chunk(tag, idx, st, emits, active_ticks=active,
+                                  trace=trace if lay.width else None,
+                                  trace_channels=lay.meta() if lay.width
+                                  else None,
+                                  run=resume_run, lane_lo=lo)
         if collect:
             chunks.append((st, emits))
 
@@ -385,23 +485,24 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
                 st, emits, active = retry_chunk(idx, lo, n_real, err)
         land_ready(idx, lo, st, emits, active)
 
-    t0 = time.perf_counter()
-    for idx, lo in enumerate(range(0, K, W)):
-        cached = reuse_chunk(idx, lo) if resume else None
-        if cached is not None:
-            # drain in-flight work first so chunks land in index order
-            while inflight:
+    with span("repro.dispatch.execute", lanes=K) as call:
+        record = _OPEN.record
+        for idx, lo in enumerate(range(0, K, W)):
+            cached = reuse_chunk(idx, lo) if resume else None
+            if cached is not None:
+                # drain in-flight work first so chunks land in index order
+                while inflight:
+                    land_oldest()
+                n_reused += 1
+                st_c, em_c, tr_c, ac_c = cached
+                land_ready(idx, lo, st_c, em_c, ac_c, trace=tr_c,
+                           spool=False)
+                continue
+            inflight.append((idx, lo) + compute(idx, lo))
+            if len(inflight) >= max(1, plan.pipeline_depth):
                 land_oldest()
-            n_reused += 1
-            st_c, em_c, tr_c, ac_c = cached
-            land_ready(idx, lo, st_c, em_c, ac_c, trace=tr_c, spool=False)
-            continue
-        inflight.append((idx, lo) + compute(idx, lo))
-        if len(inflight) >= max(1, plan.pipeline_depth):
+        while inflight:
             land_oldest()
-    while inflight:
-        land_oldest()
-    wall_s = time.perf_counter() - t0
 
     LAST_ACTIVE = np.concatenate(actives) if actives else np.zeros(0, np.int32)
     ACTIVE_LOG.append((tag, LAST_ACTIVE))
@@ -413,20 +514,19 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
     else:
         LAST_TRACE = None
 
-    active_total = int(LAST_ACTIVE.sum())
     LAST_TIMING = {
         "tag": tag,
         "kernel_impl": engine.static_cfg(cfg).proto.kernel_impl,
-        "wall_s": wall_s,
+        "wall_s": call.dur_ns / 1e9,
         "lanes": K,
         "n_ticks": plan.n_ticks,
-        "active_ticks_total": active_total,
-        "tick_wall_us": wall_s * 1e6 / max(active_total, 1),
+        "active_ticks_total": int(LAST_ACTIVE.sum()),
         "retries": n_retries,
         "chunks_reused": n_reused,
         "budget_source": plan.budget_source,
         "devices": plan.n_devices,
         "out_devices": out_devices,
+        "stages": self_seconds(record, call),
     }
     TIMING_LOG.append(LAST_TIMING)
 

@@ -310,8 +310,9 @@ def derive(env: PhaseEnv, st, ops, topo) -> StepCtx:
     else:
         qpaused = jnp.zeros((P, Q), bool)
 
-    n_active = jnp.maximum(((occ > 0) & ~qpaused).sum(axis=1), 1)  # (P,)
-    th = pause_threshold(n_active, tm.pause_window)                # (P,)
+    with jax.named_scope("switch_decision"):
+        n_active = jnp.maximum(((occ > 0) & ~qpaused).sum(axis=1), 1)
+        th = pause_threshold(n_active, tm.pause_window)            # (P,)
 
     # PFC state (hysteresis: pause above th, resume below th/2)
     if pc.pfc:
@@ -344,10 +345,11 @@ def derive(env: PhaseEnv, st, ops, topo) -> StepCtx:
         blocked = pfc_paused | topo.port_is_nic
         srf_key = (jnp.minimum(st.qsrf, BIG) if pc.scheduler == "srf"
                    else None)
-        _, th, _, ksel, kcan, kocc = kernel_ops.fused(
-            occ, qpaused, st.qptr, blocked, srf_key=srf_key,
-            pause_window=tm.pause_window, scheduler=pc.scheduler,
-            impl=pc.kernel_impl)
+        with jax.named_scope("switch_decision"):
+            _, th, _, ksel, kcan, kocc = kernel_ops.fused(
+                occ, qpaused, st.qptr, blocked, srf_key=srf_key,
+                pause_window=tm.pause_window, scheduler=pc.scheduler,
+                impl=pc.kernel_impl)
 
     return StepCtx(t=t, occ=occ, port_occ=port_occ, sw_occ=sw_occ,
                    qpaused=qpaused, th=th, pfc_paused=pfc_paused,

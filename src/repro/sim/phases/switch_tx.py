@@ -8,6 +8,7 @@ last queued packet departs release their queue and (if paused) their
 upstream Bloom-filter bits."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core import bloom
@@ -31,16 +32,17 @@ def switch_tx(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
         can_tx = ctx.kcan_tx
         sel_q = jnp.where(can_tx, ctx.ksel_q, 0)
     else:
-        eligible = (occ > 0) & ~ctx.qpaused & ~ctx.pfc_paused[:, None] \
-            & ~topo.port_is_nic[:, None]
-        if pc.scheduler == "srf":
-            key = jnp.minimum(st.qsrf, BIG)
-        else:
-            key = (q_ar[None, :] - st.qptr[:, None]) % Q
-        key = jnp.where(eligible, key, BIG + 1)
-        packed = key * Q + q_ar[None, :]               # fits int32
-        sel_q = (jnp.min(packed, axis=1) % Q).astype(I32)
-        can_tx = eligible[p_ar, sel_q]
+        with jax.named_scope("switch_decision"):
+            eligible = (occ > 0) & ~ctx.qpaused & ~ctx.pfc_paused[:, None] \
+                & ~topo.port_is_nic[:, None]
+            if pc.scheduler == "srf":
+                key = jnp.minimum(st.qsrf, BIG)
+            else:
+                key = (q_ar[None, :] - st.qptr[:, None]) % Q
+            key = jnp.where(eligible, key, BIG + 1)
+            packed = key * Q + q_ar[None, :]               # fits int32
+            sel_q = (jnp.min(packed, axis=1) % Q).astype(I32)
+            can_tx = eligible[p_ar, sel_q]
     tx_entry = jnp.where(
         can_tx, st.qbuf[p_ar, sel_q, st.qhead[p_ar, sel_q] % CAP], -1)
     tx_f = jnp.maximum(tx_entry >> 1, 0)

@@ -69,6 +69,7 @@ import numpy as np
 from . import engine, metrics
 from .config import SimConfig
 from .engine import FlowOperands, SimState
+from .exec.dispatch import span
 from .topology import (MAX_HOPS, TopoDims, TopoOperands, Topology,
                        build_cached, pack_topo)
 from .workload import FlowSet
@@ -289,9 +290,10 @@ def run_batch(topo: Union[Topology, Sequence[Topology]],
     if plan is None:
         budget = (max_batch_bytes if max_batch_bytes is not None
                   else ("auto" if auto_budget else None))
-        plan = exec_.plan(dims, cfg, f_max, n_ticks, K, devices=devices,
-                          budget=budget, unroll=unroll,
-                          early_exit=early_exit)
+        with span("repro.exec.plan", lanes=K):
+            plan = exec_.plan(dims, cfg, f_max, n_ticks, K,
+                              devices=devices, budget=budget, unroll=unroll,
+                              early_exit=early_exit)
     return exec_.execute(plan, topos, flowsets, cfg, store=store,
                          tag=cfg.proto.name, resume=resume)
 
@@ -339,43 +341,51 @@ def run_grid(topo: Topology,
     across calls. `devices` / `auto_budget` / `max_batch_bytes` / `store`
     / `resume` configure each group's `exec.ExecPlan` (see `run_batch`;
     with `resume=True` each protocol group independently reuses whatever
-    chunks its interrupted run spooled)."""
-    if n_ticks is None:
-        n_ticks = int(max(f.horizon for _, _, f in cases) + drain)
-    # group key: the compile signature — the protocol/timing config alone.
-    # NOTHING about a fabric keys the grouping: ports/servers/switches pad
-    # to a union TopoDims and link latency wraps at the traced per-lane
-    # prop_ticks modulus, so mixed-latency grids batch into one program.
-    groups: Dict[SimConfig, List[int]] = {}
-    for i, (_, cfg, _) in enumerate(cases):
-        groups.setdefault(engine.static_cfg(cfg), []).append(i)
+    chunks its interrupted run spooled).
 
-    topos = [_case_topo(cfg, topo) for _, cfg, _ in cases]
-    results: List[Optional[CaseResult]] = [None] * len(cases)
-    for idxs in groups.values():
-        flowsets = [cases[i][2] for i in idxs]
-        group_topos = [topos[i] for i in idxs]
-        cfg = cases[idxs[0]][1]
-        st, emits = run_batch(group_topos, flowsets, cfg, n_ticks, unroll,
-                              pad_multiple, max_batch_bytes=max_batch_bytes,
-                              devices=devices, auto_budget=auto_budget,
-                              store=store, early_exit=early_exit,
-                              resume=resume)
-        for k, i in enumerate(idxs):
-            label, case_cfg, flows = cases[i]
-            case_topo = group_topos[k]
-            state_k = select_config(st, k, flows.n_flows,
-                                    TopoDims.of(case_topo))
-            m = None
-            if summarize:
-                m = metrics.summarize(
-                    label, state_k, emits[k], flows,
-                    n_links=case_topo.n_ports,
-                    occ_bin_ref=case_topo.params.switch_buffer_pkts,
-                    cap=case_cfg.proto.queue_cap)
-            results[i] = CaseResult(label=label, proto=case_cfg.proto.name,
-                                    cfg=case_cfg, flows=flows,
-                                    state=state_k, emits=emits[k],
-                                    metrics=m)
-    return results
+    The call is one `repro.sweep.run_grid` span (`exec.dispatch.span`);
+    each protocol group, and each case's selection and summary, are
+    spans under it."""
+    with span("repro.sweep.run_grid", lanes=len(cases)):
+        if n_ticks is None:
+            n_ticks = int(max(f.horizon for _, _, f in cases) + drain)
+        # group key: the compile signature — the protocol/timing config
+        # alone. NOTHING about a fabric keys the grouping: ports/servers/
+        # switches pad to a union TopoDims and link latency wraps at the
+        # traced per-lane prop_ticks modulus, so mixed-latency grids batch
+        # into one program.
+        groups: Dict[SimConfig, List[int]] = {}
+        for i, (_, cfg, _) in enumerate(cases):
+            groups.setdefault(engine.static_cfg(cfg), []).append(i)
 
+        topos = [_case_topo(cfg, topo) for _, cfg, _ in cases]
+        results: List[Optional[CaseResult]] = [None] * len(cases)
+        for idxs in groups.values():
+            with span("repro.sweep.group", lanes=len(idxs)):
+                flowsets = [cases[i][2] for i in idxs]
+                group_topos = [topos[i] for i in idxs]
+                cfg = cases[idxs[0]][1]
+                st, emits = run_batch(
+                    group_topos, flowsets, cfg, n_ticks, unroll,
+                    pad_multiple, max_batch_bytes=max_batch_bytes,
+                    devices=devices, auto_budget=auto_budget, store=store,
+                    early_exit=early_exit, resume=resume)
+                for k, i in enumerate(idxs):
+                    label, case_cfg, flows = cases[i]
+                    case_topo = group_topos[k]
+                    with span("repro.sweep.select", case=i):
+                        state_k = select_config(st, k, flows.n_flows,
+                                                TopoDims.of(case_topo))
+                    m = None
+                    if summarize:
+                        with span("repro.sweep.summarize", case=i):
+                            m = metrics.summarize(
+                                label, state_k, emits[k], flows,
+                                n_links=case_topo.n_ports, occ_bin_ref=(
+                                    case_topo.params.switch_buffer_pkts),
+                                cap=case_cfg.proto.queue_cap)
+                    results[i] = CaseResult(
+                        label=label, proto=case_cfg.proto.name,
+                        cfg=case_cfg, flows=flows, state=state_k,
+                        emits=emits[k], metrics=m)
+        return results
