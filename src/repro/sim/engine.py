@@ -63,6 +63,11 @@ PHANTOM_ARRIVAL = int(1 << 30)
 # must agree on it for the one-compilation-per-protocol contract to hold.
 DEFAULT_SEGMENT = 512
 
+# Name of the vmapped lane axis of a batched runner, over which a per-tick
+# conditional reduces its predicate (`phases.ctx.lane_any`). Distinct from
+# the ``lanes`` mesh axis that `shard_map` splits the batch over.
+LANE_AXIS = "lane"
+
 
 class FlowOperands(NamedTuple):
     """Per-flow metadata fed to the jitted step as traced operands.
@@ -177,16 +182,18 @@ class SimState(NamedTuple):
     qlen_hist: jnp.ndarray         # (BINS,) physical queue length histogram
 
 
-def make_step(dims: TopoDims, cfg: SimConfig, n_flows: int):
+def make_step(dims: TopoDims, cfg: SimConfig, n_flows: int,
+              lane_axis: str | None = None):
     """Build (init_state, step) for one static program signature.
 
     Only `dims` (topology shapes) and the protocol/timing config shape the
     program; per-flow metadata (`FlowOperands`) AND per-fabric tables
     (`TopoOperands`) arrive at trace time as operands of `step`, so one
     compiled program serves every workload on every same-shaped fabric.
-    `cfg.clos` is deliberately unused here — strip it from cache keys."""
+    `cfg.clos` is deliberately unused here — strip it from cache keys.
+    `lane_axis` names the vmap axis when the step runs batched."""
     pc, tm = cfg.proto, cfg.timing
-    env = phases.make_env(dims, cfg, n_flows)
+    env = phases.make_env(dims, cfg, n_flows, lane_axis)
     P, NSRV, NSW, PROP = env.P, env.NSRV, env.NSW, env.PROP_MAX
     Q, CAP, PLCAP, S = env.Q, env.CAP, env.PLCAP, env.S
     F, H, RING, RRING = env.F, env.H, env.RING, env.RRING
@@ -407,7 +414,8 @@ def compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
 def _compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
                      n_ticks: int, unroll: int, batched: bool,
                      segment: int, early_exit: bool, devices):
-    init_state, step = make_step(dims, cfg, n_flows)
+    init_state, step = make_step(dims, cfg, n_flows,
+                                 LANE_AXIS if batched else None)
     env = phases.make_env(dims, cfg, n_flows)
     # emit row width: 3 legacy columns + the opt-in trace channels
     # (0 with the default off-spec, so the buffer shape is unchanged)
@@ -461,8 +469,9 @@ def _compiled_runner(dims: TopoDims, cfg: SimConfig, n_flows: int,
     def go(flow_ops, topo_ops):
         TRACE_EVENTS.append((cfg.proto.name, dims, n_flows, n_ticks,
                              batched))
-        return (jax.vmap(one)(flow_ops, topo_ops) if batched
-                else one(flow_ops, topo_ops))
+        if batched:
+            return jax.vmap(one, axis_name=LANE_AXIS)(flow_ops, topo_ops)
+        return one(flow_ops, topo_ops)
 
     if devices is not None and len(devices) > 1:
         if not batched:

@@ -5,7 +5,9 @@ resume ring (the paper's buffer optimization; disabled by the
 `resume_limit=False` ablation), clears its pause bit, decrements the
 upstream counting Bloom filter, and rotates the filter pipeline
 counts -> in-flight snapshot -> applied snapshot every tau (modeling pause
-frame propagation delay).
+frame propagation delay). The pop runs only on a tick where some lane of
+the program has something to pop (docs/ARCHITECTURE.md, "The phase
+pipeline").
 
 The resume gate compares occupancy against `ctx.th` — on the kernelized
 switch path (`ProtoConfig.kernel_impl`) that threshold comes from the
@@ -24,10 +26,11 @@ first-hop ToR is a couple of ticks instead of an end-to-end RTT. The
 `sfc_until`; `nic_tx` gates eligibility on it."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core import bloom
-from .ctx import I32, PhaseEnv, StepCtx, hop_of_port
+from .ctx import I32, PhaseEnv, StepCtx, hop_of_port, lane_any
 
 
 def control(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
@@ -48,25 +51,39 @@ def control(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
             do_pop = pending & below & is_tau   # <=1 per queue per tau
         else:
             do_pop = pending & below            # ablation: no throttling
-        cand = jnp.take_along_axis(
-            st.pl, (pl_head % PLCAP)[..., None], axis=2)[..., 0]  # (P,Q)
-        cand_f = jnp.maximum(cand, 0)
-        cand_hop = hop_of_port(ops.routes, cand_f, p_ar[:, None])  # (P,Q)
-        valid = (do_pop & (cand >= 0)
-                 & (st.f_q[cand_f, cand_hop] == q_ar[None, :])
-                 & st.f_paused[cand_f, cand_hop]
-                 & (st.f_cnt[cand_f, cand_hop] > 0))
-        pl_head = pl_head + do_pop.astype(I32)
-        # unpause (scatter with OOB-drop for invalid lanes)
-        flat_f = jnp.where(valid, cand_f, F).reshape(-1)
-        flat_hop = cand_hop.reshape(-1)
-        f_paused = f_paused.at[flat_f, flat_hop].set(False)
-        up_port = ops.routes[cand_f.reshape(-1),
-                             jnp.maximum(cand_hop.reshape(-1) - 1, 0)]
-        bloom_counts = bloom.add_batch(
-            bloom_counts, jnp.maximum(up_port, 0),
-            ops.fpos[cand_f.reshape(-1)],
-            jnp.where(valid.reshape(-1), -1, 0))
+
+        def resume(carry):
+            pl_head, f_paused, bloom_counts = carry
+            with jax.named_scope("control.resume"):
+                cand = jnp.take_along_axis(
+                    pl, (pl_head % PLCAP)[..., None], axis=2)[..., 0]  # (P,Q)
+                cand_f = jnp.maximum(cand, 0)
+                cand_hop = hop_of_port(ops.routes, cand_f,
+                                       p_ar[:, None])                 # (P,Q)
+                valid = (do_pop & (cand >= 0)
+                         & (st.f_q[cand_f, cand_hop] == q_ar[None, :])
+                         & st.f_paused[cand_f, cand_hop]
+                         & (st.f_cnt[cand_f, cand_hop] > 0))
+                pl_head = pl_head + do_pop.astype(I32)
+                # unpause (scatter with OOB-drop for invalid lanes)
+                flat_f = jnp.where(valid, cand_f, F).reshape(-1)
+                flat_hop = cand_hop.reshape(-1)
+                f_paused = f_paused.at[flat_f, flat_hop].set(False)
+                up_port = ops.routes[cand_f.reshape(-1),
+                                     jnp.maximum(cand_hop.reshape(-1) - 1, 0)]
+                bloom_counts = bloom.add_batch(
+                    bloom_counts, jnp.maximum(up_port, 0),
+                    ops.fpos[cand_f.reshape(-1)],
+                    jnp.where(valid.reshape(-1), -1, 0))
+            return pl_head, f_paused, bloom_counts
+
+        # With nothing to pop `resume` is the identity, so a tick on which
+        # no lane pops skips it without changing a bit (with `resume_limit`,
+        # 11 ticks of 12). The predicate is reduced over the program's lanes
+        # (`lane_any`): a per-lane one would make vmap run both branches.
+        pl_head, f_paused, bloom_counts = jax.lax.cond(
+            lane_any(env, do_pop), resume, lambda carry: carry,
+            (pl_head, f_paused, bloom_counts))
         # rotate the filter pipeline every tau (models propagation delay)
         bloom_rx = jnp.where(is_tau, bloom_mid, bloom_rx)
         bloom_mid = jnp.where(is_tau, bloom.snapshot(bloom_counts),

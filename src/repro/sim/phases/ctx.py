@@ -31,6 +31,9 @@ class PhaseEnv(NamedTuple):
     RING: int                # feedback ring length (worst-case delay + 2)
     RRING: int               # retransmit ring length (rto + 1)
     bparams: bloom.BloomParams
+    # name of the vmapped lane axis in a batched runner, None unbatched:
+    # a per-tick conditional reduces its predicate over it (`lane_any`)
+    lane_axis: Optional[str] = None
 
     @property
     def P(self) -> int:
@@ -75,7 +78,8 @@ class PhaseEnv(NamedTuple):
         return self.cfg.timing.tau_ticks
 
 
-def make_env(dims: TopoDims, cfg: SimConfig, n_flows: int) -> PhaseEnv:
+def make_env(dims: TopoDims, cfg: SimConfig, n_flows: int,
+             lane_axis: Optional[str] = None) -> PhaseEnv:
     # feedback ring sized for the worst-case one-way delay of the slowest
     # lane (static so the compiled program is independent of the workload's
     # actual hop counts and of each lane's true prop_ticks: a ring is a
@@ -84,7 +88,21 @@ def make_env(dims: TopoDims, cfg: SimConfig, n_flows: int) -> PhaseEnv:
                     RING=MAX_HOPS * dims.prop_max + 2,
                     RRING=cfg.timing.rto_ticks + 1,
                     bparams=bloom.BloomParams(cfg.bloom_stages,
-                                              cfg.bloom_stage_bits))
+                                              cfg.bloom_stage_bits),
+                    lane_axis=lane_axis)
+
+
+def lane_any(env: PhaseEnv, x) -> jnp.ndarray:
+    """`jnp.any(x)` over this lane and every other lane of the program.
+
+    The predicate of a per-tick `lax.cond`: under vmap a per-lane predicate
+    turns the cond into a select that runs both branches, while one reduced
+    over the named lane axis stays a real conditional. Under `shard_map`
+    each device reduces over its own lanes only."""
+    hit = jnp.any(x)
+    if env.lane_axis is None:
+        return hit
+    return jax.lax.pmax(hit.astype(I32), env.lane_axis) > 0
 
 
 class StepCtx(NamedTuple):

@@ -7,6 +7,7 @@ delivery, feedback booking, histogram masking — is checkable in isolation
 from the full scan."""
 import importlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,8 +15,8 @@ import pytest
 pytestmark = pytest.mark.tier1
 
 from repro.core import bloom
-from repro.sim import engine, phases, topology, workload
-from repro.sim.config import BFC, SimConfig
+from repro.sim import engine, phases, sweep, topology, workload
+from repro.sim.config import BFC, BFC_NO_BUFOPT, DCQCN, SimConfig
 from repro.sim.topology import ClosParams, TopoDims, pack_topo
 
 CLOS = ClosParams(n_servers=8, n_tor=2, n_spine=2, switch_buffer_pkts=512)
@@ -69,8 +70,8 @@ def test_derive_initial_tick():
     assert np.array_equal(np.asarray(ctx.rem_src), want)
 
 
-def test_control_pops_resume_ring_at_tau():
-    env, st, ops, tops, topo, flows = _setup()
+def _pending_resume(st, ops, flows):
+    """A state whose resume ring holds one paused, below-threshold flow."""
     routes = np.asarray(flows.routes)
     f = int(np.argmax((routes >= 0).sum(1) >= 2))  # any multi-hop flow
     hop, p = 1, int(routes[f, 1])
@@ -84,10 +85,72 @@ def test_control_pops_resume_ring_at_tau():
         pl=st.pl.at[p, 0, 0].set(f),
         pl_tail=st.pl_tail.at[p, 0].set(1),
         bloom_counts=counts)
+    return st, f, hop, p
+
+
+def test_control_pops_resume_ring_at_tau():
+    env, st, ops, tops, topo, flows = _setup()
+    st, f, hop, p = _pending_resume(st, ops, flows)
     ctx = _through(env, st, ops, tops, upto=1)   # t=0 is a tau boundary
     assert not bool(np.asarray(ctx.f_paused)[f, hop])
     assert int(np.asarray(ctx.pl_head)[p, 0]) == 1
     assert int(np.asarray(ctx.bloom_counts).sum()) == 0  # filter cleaned
+
+
+def test_control_skips_resume_pop_between_tau_boundaries():
+    """Off a tau boundary no queue may pop (`resume_limit`), so the resume
+    block is skipped and leaves its state untouched bit for bit."""
+    env, st, ops, tops, topo, flows = _setup()
+    st, f, hop, p = _pending_resume(st, ops, flows)
+    st = st._replace(t=jnp.int32(1))
+    assert env.TAU > 1
+    ctx = _through(env, st, ops, tops, upto=1)
+    for name in ("pl_head", "f_paused", "bloom_counts"):
+        assert np.array_equal(np.asarray(getattr(ctx, name)),
+                              np.asarray(getattr(st, name))), name
+    assert bool(np.asarray(ctx.f_paused)[f, hop])
+
+
+def _eqns(jaxpr, in_cond=False):
+    """(eqn, inside a cond branch) for every equation of `jaxpr`, nested
+    ones included."""
+    for e in jaxpr.eqns:
+        yield e, in_cond
+        inner = in_cond or e.primitive.name == "cond"
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, inner)
+
+
+@pytest.mark.parametrize("proto,gated", [(BFC, True), (BFC_NO_BUFOPT, True),
+                                         (DCQCN, False)],
+                         ids=["bfc", "bfc_nobufopt", "dcqcn"])
+def test_batched_runner_keeps_resume_pop_under_cond(proto, gated):
+    """In the batched (vmapped) runner the resume gathers and scatters lie
+    inside a real `cond`: their predicate is reduced over the lanes, so vmap
+    does not turn the conditional into a select that runs them every tick.
+    A protocol without backpressure traces no resume block at all."""
+    topo = topology.build(CLOS)
+    dims = TopoDims.of(topo)
+    cfg = SimConfig(proto=proto, clos=CLOS)
+    flowsets = [workload.generate(
+        topo, workload.WorkloadParams(workload="uniform", load=0.5,
+                                      seed=seed), 12) for seed in (1, 2)]
+    go = engine.compiled_runner(dims, cfg, 12, 512, batched=True)
+    scfg = engine.static_cfg(cfg)
+    jaxpr = jax.make_jaxpr(go)(
+        sweep.stack_operands(flowsets, scfg, 12),
+        sweep.stack_topos([topo] * 2, scfg, dims)).jaxpr
+    resume = [(e.primitive.name, inside) for e, inside in _eqns(jaxpr)
+              if "control.resume" in str(e.source_info.name_stack)]
+    if gated:
+        assert resume and all(inside for _, inside in resume), \
+            "resume ops must all lie inside a cond branch"
+        assert "scatter" in {name for name, _ in resume}
+    else:
+        assert not resume
 
 
 def test_switch_tx_dequeues_head_and_releases_queue():

@@ -6,8 +6,8 @@ import pytest
 pytestmark = pytest.mark.tier1
 
 from repro.sim import engine, scenarios, sweep, topology, workload
-from repro.sim.config import BFC, PRESETS, SimConfig
-from repro.sim.topology import ClosParams
+from repro.sim.config import BFC, BFC_NO_BUFOPT, PRESETS, SimConfig
+from repro.sim.topology import ClosParams, TopoDims, pack_topo
 
 CLOS = ClosParams(n_servers=16, n_tor=2, n_spine=2, switch_buffer_pkts=2048)
 
@@ -93,6 +93,50 @@ def test_conservation_across_registry_scenarios(scenario_name):
         assert (np.asarray(st.delivered) <= r.flows.size_pkts).all(), r.label
         done = np.asarray(st.done)
         assert (done >= 0).mean() > 0.9, f"{r.label}: too few completed"
+
+
+@pytest.mark.parametrize("proto", [BFC, BFC_NO_BUFOPT],
+                         ids=lambda p: p.name)
+def test_batched_resume_gate_matches_each_lanes_own_run(proto):
+    """The resume pop runs on a tick where ANY lane of the batch pops
+    (`phases.ctx.lane_any`). A gate opened by one lane must change no other
+    lane: every lane's final SimState, emits and active ticks equal its own
+    unbatched run, with and without `resume_limit`."""
+    clos = ClosParams(n_servers=8, n_tor=2, n_spine=2, switch_buffer_pkts=512)
+    topo = topology.build(clos)
+    dims = TopoDims.of(topo)
+    cfg = SimConfig(proto=proto, clos=clos)
+    flowsets = [workload.generate(
+                    topo, workload.WorkloadParams(
+                        workload="fb_hadoop", load=0.8, seed=seed,
+                        incast_load=0.2, incast_degree=6,
+                        incast_total_kb=600), 24)
+                for seed in (11, 12, 13)]
+    n = max(f.n_flows for f in flowsets)
+    n_ticks = 2048
+    go = engine.compiled_runner(dims, cfg, n, n_ticks, batched=True,
+                                segment=256)
+    st_b, em_b, act_b = go(
+        sweep.stack_operands(flowsets, engine.static_cfg(cfg), n),
+        sweep.stack_topos([topo] * 3, engine.static_cfg(cfg), dims))
+    # the lanes resume different flows and go quiescent at different ticks,
+    # so the gate opens for some lanes and not others
+    pops = np.asarray(st_b.pl_head).sum(axis=(1, 2))
+    assert pops.min() > 0 and len(set(pops.tolist())) == 3, pops
+    assert len(set(np.asarray(act_b).tolist())) > 1, act_b
+
+    serial = engine.compiled_runner(dims, cfg, n, n_ticks, segment=256)
+    for k, flows in enumerate(flowsets):
+        st_s, em_s, act_s = serial(
+            engine.pack_flows(sweep.pad_flowset(flows, n), cfg),
+            pack_topo(topo, infinite_buffer=proto.infinite_buffer))
+        assert int(act_b[k]) == int(act_s), f"lane {k} active ticks"
+        assert np.array_equal(np.asarray(em_b[k]), np.asarray(em_s)), \
+            f"lane {k} emits"
+        for name in st_s._fields:
+            assert np.array_equal(np.asarray(getattr(st_b, name)[k]),
+                                  np.asarray(getattr(st_s, name))), \
+                f"lane {k} SimState.{name}"
 
 
 def test_padded_count_rounds_up(tiny_topo):
