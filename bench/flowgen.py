@@ -19,6 +19,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+# the leaf-spine's route width (NIC, ToR, spine, ToR); the reference takes
+# its hop width from the routes it is given
 MAX_HOPS = 4
 
 # (size_in_KB, CDF by count) control points, log-linear interpolation.

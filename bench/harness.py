@@ -5,9 +5,11 @@
 
 1. Resolve the cell's configuration (`configs/<config>.json`) and traffic
    mix (`traffic/<traffic>.json`) by name.
-2. Build the fabric with the program's topology module.
-3. Generate each lane's flows with the benchmark's own generator
-   (`flowgen.py`); lane i gets seed n + i.
+2. Load the configuration's module (`leaf_spine.py` unless the file
+   names another under `module`): it builds the fabric, the program's
+   case and each lane's flows, and runs the reference.
+3. Generate each lane's flows with the module's generator; lane i gets
+   seed n + i.
 4. Turn on the program's persistent compilation cache (a fixed directory
    in the checkout, or $JAX_COMPILATION_CACHE_DIR) and make one warm call.
    Everything up to here is set-up.
@@ -19,21 +21,24 @@
    under the profiler and the per-layer metrics are read from its trace
    by the readers in `metrics/`, one file per metric.
 
-Then the outputs are checked (`check.py` against `reference.py`), and the
-last line of standard output is one JSON object. On a machine whose
-first device is not a TPU in `peaks.json`, or with fewer chips than the
-cell asks for, the run exits non-zero and prints no result.
+Then the outputs are checked (`check.py` against the module's
+reference), and the last line of standard output is one JSON object. On
+a machine whose first device is not a TPU in `peaks.json`, or with fewer
+chips than the cell asks for, the run exits non-zero and prints no
+result.
 """
 from __future__ import annotations
 
 import argparse
 import importlib.util
 import json
+import multiprocessing as mp
 import os
 import shutil
 import sys
 import time
 import types
+from concurrent.futures import ProcessPoolExecutor
 from pathlib import Path
 
 import numpy as np
@@ -46,6 +51,8 @@ ROOT = HERE.parent
 AMBIENT_VARS = ("REPRO_KERNEL", "REPRO_KERNEL_INTERPRET", "REPRO_FAULTS",
                 "REPRO_EXEC_MAX_BYTES")
 TRACE_DIR = HERE / ".work" / "trace"
+DEFAULT_MODULE = "leaf_spine.py"
+MODULE_FUNCTIONS = ("fabric", "program", "generate", "simulate", "summarize")
 # float_gap's limit: above the largest reading of sound runs, below the
 # smallest of the bfloat16 control (PERF.md, "How correct is decided")
 FLOAT_GAP_LIMIT = 1e-4
@@ -106,16 +113,35 @@ def resolve(cell_name: str, root: Path = ROOT):
     return bench, cell, config, traffic
 
 
-def reader(metric: str):
-    """The `read(ctx)` function of a per-layer metric's own file."""
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    if spec is None or not path.exists():
-        raise Refused(f"no reader {path}")
+def load_module(path: Path, name: str):
+    """Execute the Python file at `path` as a module named `name`."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or not path.is_file():
+        raise Refused(f"missing {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    """The `read(ctx)` function of a per-layer metric's own file."""
+    return load_module(HERE / "metrics" / f"{metric}.py",
+                       f"bench_metric_{metric.replace('.', '_')}").read
+
+
+def module_of(config: dict):
+    """The module that builds a configuration's fabric, program case and
+    flows and runs its reference: the file its `module` key names,
+    relative to `bench/`, else the leaf-spine (`leaf_spine.py`)."""
+    path = (HERE / config.get("module", DEFAULT_MODULE)).resolve()
+    if HERE.resolve() not in path.parents:
+        raise Refused(f"module {path} lies outside {HERE}")
+    mod = load_module(path, f"bench_config_{path.stem}")
+    missing = [f for f in MODULE_FUNCTIONS
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise Refused(f"module {path} lacks {', '.join(missing)}")
+    return mod
 
 
 def metrics_of(bench: dict, cell: dict, kind: str) -> list:
@@ -147,25 +173,14 @@ def check_device(chips: int, peaks: dict):
     return devs
 
 
-def program_config(config: dict):
-    """The program's SimConfig and fabric from a configuration file."""
-    from repro.sim.config import ProtoConfig, SimConfig, TimingParams
-    from repro.sim.topology import ClosParams
-    clos = ClosParams(**config["fabric"])
-    return SimConfig(proto=ProtoConfig(**config["proto"]),
-                     timing=TimingParams(**config["timing"]), clos=clos,
-                     **config["sim"])
-
-
 def build_cases(config: dict, traffic: dict, seed: int):
     """(topology, cases, per-lane flow dicts) for one run."""
     import flowgen
-    from repro.sim.topology import build
     from repro.sim.workload import FlowSet
-    cfg = program_config(config)
-    topo = build(cfg.clos)
-    fabric = flowgen.fabric_of(config)
-    flows = [flowgen.generate(fabric, traffic, seed + i)
+    mod = module_of(config)
+    cfg, topo = mod.program(config)
+    fabric = mod.fabric(config)
+    flows = [mod.generate(fabric, traffic, seed + i)
              for i in range(traffic["lanes"])]
     cases = [(f"{config['name']}/{traffic['name']}/lane{i}", cfg,
               FlowSet(**{k: f[k] for k in flowgen.ARRAYS},
@@ -181,20 +196,37 @@ def float_rules(device_kind: str) -> dict:
     return rules[device_kind]
 
 
+def _reference_lane(job):
+    config, flows, n_ticks, rules, fdtype = job
+    mod = module_of(config)
+    fabric = mod.fabric(config)
+    st, emits = mod.simulate(fabric, config, flows, n_ticks, rules, fdtype)
+    return st, emits, mod.summarize(st, emits, flows, fabric.n_ports)
+
+
+def references(config, lane_flows, n_ticks, rules, fdtype=np.float32):
+    """The configuration's reference run over each lane's flows for
+    `n_ticks[i]` ticks: a list of (final state, emits, summary). Lanes run
+    in processes of their own, which import nothing of the program."""
+    jobs = [(config, f, n, rules, fdtype)
+            for f, n in zip(lane_flows, n_ticks)]
+    if len(jobs) < 2:
+        return [_reference_lane(j) for j in jobs]
+    with ProcessPoolExecutor(min(len(jobs), os.cpu_count() or 1),
+                             mp_context=mp.get_context("spawn")) as pool:
+        return list(pool.map(_reference_lane, jobs))
+
+
 def judge(results, flows, lanes, config, rules, fdtype=np.float32):
     """(mismatch, float_gap, details) of `lanes` of one call's results
-    against the reference."""
+    against the configuration's reference."""
     import check
-    import flowgen
-    import reference
-    fabric = flowgen.fabric_of(config)
+    refs = references(config, [flows[k] for k in lanes],
+                      [int(np.shape(results[k].emits)[0]) for k in lanes],
+                      rules, fdtype)
     mismatch, gap, details = 0, 0.0, {}
-    for k in lanes:
+    for k, (st, emits, ref_m) in zip(lanes, refs):
         r = results[k]
-        n_ticks = int(np.shape(r.emits)[0])
-        st, emits = reference.simulate(fabric, config, flows[k], n_ticks,
-                                       rules, fdtype)
-        ref_m = reference.summarize(st, emits, flows[k], fabric.n_ports)
         mm, g, det = check.compare_lane(r.state, r.emits, r.metrics, st,
                                         emits, ref_m)
         mismatch += mm
@@ -228,6 +260,7 @@ def run(argv=None, t0: float = None) -> int:
     peaks = load_json(HERE / "peaks.json")
     sys.path.insert(0, str(HERE))
     import_program()
+    module_of(config)
     import jax
     devs = check_device(cell["chips"], peaks)
     ready_s = time.perf_counter() - t0

@@ -63,22 +63,18 @@ def exercised(res, flows, n_ticks: int) -> dict:
 
 
 def control_readings(config, traffic, seed, rules, lanes):
-    """(mismatch, float_gap) of the bfloat16 reference against float32."""
+    """(mismatch, float_gap) of the configuration's bfloat16 reference
+    against its float32 reference."""
     import ml_dtypes
 
-    import flowgen
-    import reference
-    fabric = flowgen.fabric_of(config)
-    flows = [flowgen.generate(fabric, traffic, seed + i)
+    mod = harness.module_of(config)
+    fabric = mod.fabric(config)
+    flows = [mod.generate(fabric, traffic, seed + i)
              for i in range(traffic["lanes"])]
-    runs = {}
-    for k in lanes:
-        st, emits = reference.simulate(fabric, config, flows[k],
-                                       traffic["n_ticks"], rules,
-                                       ml_dtypes.bfloat16)
-        runs[k] = (st, emits, reference.summarize(st, emits, flows[k],
-                                                  fabric.n_ports))
-    fake = {k: r for k, r in zip(runs, as_results(runs.values()))}
+    runs = harness.references(config, [flows[k] for k in lanes],
+                              [traffic["n_ticks"]] * len(lanes), rules,
+                              ml_dtypes.bfloat16)
+    fake = dict(zip(lanes, as_results(runs)))
     return harness.judge(fake, flows, lanes, config, rules)[:2]
 
 

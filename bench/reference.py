@@ -36,19 +36,25 @@ runs it in bfloat16). Where the device's compiler contracts a multiply
 and an add into one rounding, or turns a division by a constant into a
 multiplication by its reciprocal, `rules` says so (see float_rules.json),
 so that the reference rounds where the device rounds.
+
+The fabric is any port graph (`n_ports`, `n_servers`, `n_switches`,
+`prop_ticks`, `switch_buffer_pkts`, `port_switch()`, `feeds()`), and the
+hop width H is the width of the flows' `routes`: the per-hop tables are
+(F, H), a packet leaves the fabric after hop H - 1 or where its route
+ends, and the feedback rings hold H * prop_ticks + 2 rows.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from flowgen import MAX_HOPS, Fabric, hash_u32
+from flowgen import hash_u32
 
 BIG = 1 << 20
 SUPPORTED_CC = ("none", "dcqcn")
 
 
 class Reference:
-    def __init__(self, fabric: Fabric, config: dict, flows: dict,
+    def __init__(self, fabric, config: dict, flows: dict,
                  rules: dict, fdtype=np.float32):
         self.fab = fabric
         self.pc = dict(config["proto"])
@@ -72,9 +78,10 @@ class Reference:
         self.F, self.P, self.Q, self.CAP, self.PLCAP = F, P, Q, CAP, PLCAP
         self.S, self.B = S, B
         self.PROP = fabric.prop_ticks
-        self.RING = MAX_HOPS * self.PROP + 2
-        self.RRING = self.tm["rto_ticks"] + 1
         self.routes = self.f["routes"].astype(np.int64)
+        self.H = H = self.routes.shape[1]
+        self.RING = H * self.PROP + 2
+        self.RRING = self.tm["rto_ticks"] + 1
         self.hops = (self.routes >= 0).sum(axis=1)
         fid = self.f["fid"]
         self.fpos = np.stack([hash_u32(fid, s) % np.uint32(B)
@@ -103,9 +110,9 @@ class Reference:
             qbuf=np.full((P, Q, CAP), -1, i64), qhead=np.zeros((P, Q), i64),
             qtail=np.zeros((P, Q), i64), qptr=np.zeros(P, i64),
             qsrf=np.full((P, Q), BIG, i64),
-            f_q=np.full((F, MAX_HOPS), -1, i64),
-            f_cnt=np.zeros((F, MAX_HOPS), i64),
-            f_paused=np.zeros((F, MAX_HOPS), bool),
+            f_q=np.full((F, H), -1, i64),
+            f_cnt=np.zeros((F, H), i64),
+            f_paused=np.zeros((F, H), bool),
             d_q=np.full((P, NSRV), -1, i64), d_cnt=np.zeros((P, NSRV), i64),
             bloom_counts=np.zeros((P, S, B), i64),
             bloom_mid=np.zeros((P, S, B), bool),
@@ -333,9 +340,9 @@ class Reference:
             entry = int(arr_entry[u])
             f, mark = entry >> 1, entry & 1
             hop = int(arr_hop[u])
-            nh = min(hop + 1, MAX_HOPS - 1)
+            nh = min(hop + 1, self.H - 1)
             nxt = int(routes[f, nh])
-            if hop + 1 >= MAX_HOPS or nxt < 0:
+            if hop + 1 >= self.H or nxt < 0:
                 st["delivered"][f] += 1
                 if (st["delivered"][f] >= self.f["size_pkts"][f]
                         and st["done"][f] < 0):
@@ -482,7 +489,7 @@ class Reference:
         return self.st, np.asarray(self.emits, np.int64).reshape(-1, 3)
 
 
-def simulate(fabric: Fabric, config: dict, flows: dict, n_ticks: int,
+def simulate(fabric, config: dict, flows: dict, n_ticks: int,
              rules: dict, fdtype=np.float32):
     """Final state (with the feedback rings in offset-from-now order) and
     the (n_ticks, 3) emit rows of one lane."""

@@ -24,9 +24,12 @@ SMALL_TRAFFIC = dict(background_flows=400, load=0.9, incast_degree=20,
 
 
 def small_config(name: str) -> dict:
+    """A configuration at a small size: the leaf-spine (the default
+    module) cut to SMALL_FABRIC; one that names its own module as it is."""
     doc = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    doc["fabric"] = dict(doc["fabric"], **SMALL_FABRIC)
-    doc["fabric"]["switch_buffer_pkts"] = 1200
+    if "module" not in doc:
+        doc["fabric"] = dict(doc["fabric"], **SMALL_FABRIC,
+                             switch_buffer_pkts=1200)
     return doc
 
 

@@ -57,6 +57,96 @@ def test_new_cell_from_files_alone(tmp_path):
     assert "device.idle_frac" in per_layer
 
 
+RECORDING_MODULE = """\"\"\"The leaf-spine, each function recording that it was called.\"\"\"
+from pathlib import Path
+
+import leaf_spine
+
+CALLS = Path(__file__).with_name("calls.txt")
+
+
+def _recorded(name):
+    def call(*args, **kwargs):
+        with open(CALLS, "a") as fh:
+            fh.write(name + "\\n")
+        return getattr(leaf_spine, name)(*args, **kwargs)
+    return call
+
+
+fabric, program, generate, simulate, summarize = map(
+    _recorded, ("fabric", "program", "generate", "simulate", "summarize"))
+"""
+
+
+def test_new_configuration_from_files_alone(tmp_path):
+    """A configuration added as a configuration file naming its module,
+    the module, a traffic file and its BENCHMARK.json entries runs through
+    `harness.run` in a copy of the tree, with the harness's files as they
+    are, and is judged correct."""
+    from conftest import small_config, small_traffic
+    ignore = shutil.ignore_patterns(".work", "__pycache__", ".jax_cache")
+    shutil.copytree(BENCH, tmp_path / "bench", ignore=ignore)
+    shutil.copytree(ROOT / "src", tmp_path / "src", ignore=ignore)
+    config = dict(small_config("bfc_paper"), name="recorded_leaf_spine",
+                  module="recorded_leaf_spine.py")
+    (tmp_path / "bench" / "configs" / "recorded_leaf_spine.json").write_text(
+        json.dumps(config))
+    (tmp_path / "bench" / "recorded_leaf_spine.py").write_text(
+        RECORDING_MODULE)
+    (tmp_path / "bench" / "traffic" / "fig6_small.json").write_text(
+        json.dumps({**small_traffic("fig6_x1", n_ticks=200),
+                    "name": "fig6_small"}))
+    bench = dict(BENCHMARK)
+    bench["configs"] = BENCHMARK["configs"] + [{
+        "name": "recorded_leaf_spine", "source": config["source"],
+        "file": "bench/configs/recorded_leaf_spine.json",
+        "reduced": config["reduced"], "why": "a module of its own"}]
+    bench["workloads"] = BENCHMARK["workloads"] + [{
+        "name": "recorded_leaf_spine.fig6_small",
+        "config": "recorded_leaf_spine", "traffic": "fig6_small",
+        "chips": 1, "why": "files alone"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    script = """
+import sys, jax
+sys.path.insert(0, "bench")
+import harness
+harness.check_device = lambda chips, peaks: jax.devices()[:chips]
+sys.exit(harness.run(["--workload", "recorded_leaf_spine.fig6_small",
+                      "--seed", "4294967311", "--seconds", "0.2"]))
+"""
+    out = subprocess.run([sys.executable, "-c", script], cwd=tmp_path,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["check"]["mismatch"]["value"] == 0
+    calls = set((tmp_path / "bench" / "calls.txt").read_text().split())
+    assert calls == set(harness.MODULE_FUNCTIONS)
+    for name in ("harness.py", "flowgen.py", "reference.py", "check.py",
+                 "limits.py", "leaf_spine.py"):
+        assert ((tmp_path / "bench" / name).read_bytes()
+                == (BENCH / name).read_bytes()), name
+
+
+@pytest.mark.parametrize("module, why", [
+    ("tests/data/no_such_module.py", "missing"),
+    ("tests/data/partial_module.py", "lacks summarize"),
+    ("../src/repro/__init__.py", "outside"),
+])
+def test_incomplete_module_is_refused(module, why, small_cell, capsys):
+    """A named module that is missing, lacks one of the five functions or
+    lies outside the benchmark's directory is refused with no result
+    line."""
+    _, _, config, _ = small_cell("bfc_paper.fig6_x1")
+    config["module"] = module
+    with pytest.raises(harness.Refused):
+        harness.run(["--workload", "bfc_paper.fig6_x1", "--seed", "3",
+                     "--seconds", "0.1"])
+    out = capsys.readouterr()
+    assert "{" not in out.out and why in out.err
+
+
 def _run_cli(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(
@@ -101,21 +191,25 @@ def test_small_run_is_correct(cell, small_cell, capsys):
     assert res["check"]["mismatch"]["value"] == 0
 
 
-def test_four_chip_cell_on_virtual_devices(tmp_path):
-    """The lane-sharded path (the `fig6_x8_4chip` mix, not yet a cell) on
-    four virtual CPU devices."""
+FOUR_CHIP = "bfc_paper.fig6_x8_4chip"
+
+
+def _run_four_chips(cwd, fault: str = ""):
+    """A small run of the four-chip cell on four virtual CPU devices, with
+    `fault` (a planting function of this module) applied first."""
     script = f"""
-import sys, json
+import sys
 sys.path[:0] = [{str(BENCH)!r}, {str(BENCH / 'tests')!r}]
-import jax, conftest, harness
-bench = json.loads(open({str(ROOT / 'BENCHMARK.json')!r}).read())
-cell = dict(name='bfc_paper.fig6_x8_4chip', config='bfc_paper',
-            traffic='fig6_x8_4chip', chips=4)
-config = conftest.small_config('bfc_paper')
-traffic = conftest.small_traffic('fig6_x8_4chip', n_ticks=120)
+import jax, pytest, conftest, harness
+bench, cell, config, traffic = harness.resolve({FOUR_CHIP!r})
+config = conftest.small_config(cell['config'])
+traffic = conftest.small_traffic(cell['traffic'], n_ticks=120)
 harness.resolve = lambda name: (bench, cell, config, traffic)
 harness.check_device = lambda chips, peaks: jax.devices()[:chips]
-assert len(jax.devices()) == 4
+assert cell['chips'] == len(jax.devices()) == 4
+if {fault!r}:
+    import test_harness
+    getattr(test_harness, {fault!r})(pytest.MonkeyPatch())
 sys.exit(harness.run(['--workload', cell['name'], '--seed', '5',
                       '--seconds', '0.1']))
 """
@@ -123,10 +217,18 @@ sys.exit(harness.run(['--workload', cell['name'], '--seed', '5',
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600,
-                         cwd=tmp_path)
+                         cwd=cwd)
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["correct"] is True and res["device"]["count"] == 4
+    assert res["device"]["count"] == 4
+    return res
+
+
+def test_four_chip_cell_on_virtual_devices(tmp_path):
+    """The four-chip cell's lane-sharded path (shard_map over `lanes`, two
+    lanes a device) on four virtual CPU devices."""
+    res = _run_four_chips(tmp_path)
+    assert res["correct"] is True and res["failed"] == 0
 
 
 # ---- faults planted under the timed path ------------------------------------
@@ -182,6 +284,31 @@ def _half_batch(monkeypatch):
                                 np.zeros_like(np.asarray(emits)[h:])])
         return st, emits
     monkeypatch.setattr(sweep, "run_batch", half)
+
+
+def _one_shard_for_all(monkeypatch):
+    """The gather from the chips left out: every lane read back from the
+    first device's shard."""
+    import numpy as np
+    from repro.sim.exec import dispatch
+    land = dispatch._land
+
+    def one_shard(st, emits, active, n_real):
+        per = len(emits.addressable_shards[0].data)
+        st, emits, active = land(st, emits, active, n_real)
+
+        def first(x):
+            return np.resize(np.asarray(x)[:per], np.shape(x))
+        return (type(st)(*map(first, st)), first(emits), first(active))
+    monkeypatch.setattr(dispatch, "_land", one_shard)
+
+
+@pytest.mark.parametrize("fault", ["_frozen_step", "_half_batch",
+                                   "_one_shard_for_all", "_altered_token"])
+def test_four_chip_fault_is_not_correct(fault, tmp_path):
+    res = _run_four_chips(tmp_path, fault)
+    assert res["correct"] is False
+    assert res["check"]["mismatch"]["value"] > 0
 
 
 @pytest.mark.parametrize("cell, fault", [
