@@ -210,16 +210,15 @@ def fig20_buffer_optimization():
         rng = np.random.default_rng(20)
         src = rng.permutation(np.arange(1, 16))[:min(n_conc, 15)]
         src = np.resize(src, n_conc)
+        fid = np.arange(n_conc, dtype=np.int32) * 7919 + 13
         flows = wl.FlowSet(
             src=src.astype(np.int32),
             dst=np.zeros(n_conc, np.int32),
             size_pkts=np.full(n_conc, 4000, np.int32),
             arrival_tick=np.zeros(n_conc, np.int32),
-            routes=topom.routes_for_flows(topo, src,
-                                          np.zeros(n_conc, np.int64),
-                                          rng.integers(0, 2, n_conc)),
+            routes=topo.routes(src, np.zeros(n_conc, np.int64), fid),
             ideal_fct=np.full(n_conc, 4000, np.int32),
-            fid=np.arange(n_conc, dtype=np.int32) * 7919 + 13,
+            fid=fid,
             is_incast=np.zeros(n_conc, bool), horizon=0)
         for proto in ("bfc", "bfc_nobufopt"):
             m, st, emits, _ = run_proto(proto, flows, topo, clos=clos,

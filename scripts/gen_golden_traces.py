@@ -1,17 +1,18 @@
 #!/usr/bin/env python
 """Regenerate (or --check) the committed golden-trace fixtures.
 
-``python scripts/gen_golden_traces.py``            regenerates every
-``config.PRESETS`` family's pinned micro-trace under
-``tests/fixtures/traces/`` (one compile + one tiny traced run per family;
-``--only NAME [NAME...]`` restricts to some families).
+``python scripts/gen_golden_traces.py``            regenerates, for every
+pinned case of `repro.sim.trace.golden.CASES`, each family's pinned
+micro-trace under ``tests/fixtures/<case directory>/`` (one compile + one
+tiny traced run per family; ``--only NAME [NAME...]`` restricts to some
+families).
 
 ``python scripts/gen_golden_traces.py --check`` is the cheap CI guard:
 no simulation, just the structural freshness check from
-`repro.sim.trace.golden.check_fixtures` — every family has a fixture, no
-orphans, and each fixture's pinned parameters / channel layout match the
-code. Bit-level identity of a re-run against each fixture is asserted by
-the tier-1 test ``tests/test_golden_traces.py``.
+`repro.sim.trace.golden.check_fixtures` over every case — every family
+has a fixture, no orphans, and each fixture's pinned parameters / channel
+layout match the code. Bit-level identity of a re-run against each
+fixture is asserted by the tier-1 test ``tests/test_golden_traces.py``.
 """
 from __future__ import annotations
 
@@ -30,8 +31,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", nargs="+", metavar="NAME",
                     help="regenerate only these families")
     ap.add_argument("--out", default=None,
-                    help="fixture directory (default: the committed "
-                         "tests/fixtures/traces/)")
+                    help="fixture root (default: the committed "
+                         "tests/fixtures/)")
     args = ap.parse_args(argv)
 
     from repro.sim.trace import golden
@@ -50,12 +51,16 @@ def main(argv=None) -> int:
     if unknown:
         print(f"unknown families {unknown}; have {sorted(PRESETS)}")
         return 2
-    for name in names:
-        fx = golden.generate_fixture(PRESETS[name])
-        path = golden.save_fixture(golden.fixture_path(name, args.out), fx)
-        kb = path.stat().st_size / 1024
-        print(f"{name:<16} -> {path} ({kb:.0f} KB, "
-              f"active to tick {int(fx['active_ticks'][0])})")
+    for case in golden.CASES:
+        for name in golden.families(case):
+            if name not in names:
+                continue
+            fx = golden.generate_fixture(PRESETS[name], case)
+            path = golden.save_fixture(
+                golden.fixture_path(name, args.out, case), fx)
+            kb = path.stat().st_size / 1024
+            print(f"{case}/{name:<16} -> {path} ({kb:.0f} KB, "
+                  f"active to tick {int(fx['active_ticks'][0])})")
     return 0
 
 

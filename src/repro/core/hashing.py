@@ -43,6 +43,15 @@ def bucket_index(fid: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
     return (hash_u32(fid, 4) % jnp.uint32(n_buckets)).astype(jnp.int32)
 
 
-def ecmp_choice(fid: jnp.ndarray, n_paths: int) -> jnp.ndarray:
-    """Flow-level ECMP: consistent uplink/spine choice per flow."""
-    return (hash_u32(fid, 5) % jnp.uint32(n_paths)).astype(jnp.int32)
+# hash seeds of the ECMP choice at each tier a path climbs (seeds 0-3 are
+# the Bloom stages, 4 the flow-table bucket)
+ECMP_SEEDS = (5, 7)
+
+
+def ecmp_choice(fid: jnp.ndarray, n_paths: int,
+                level: int = 0) -> jnp.ndarray:
+    """Flow-level ECMP: consistent uplink choice per flow at tier `level`
+    (0: the first uplink, the leaf-spine's spine; 1: the next tier up),
+    by independent hashes of the flow id."""
+    return (hash_u32(fid, ECMP_SEEDS[level]) % jnp.uint32(n_paths)
+            ).astype(jnp.int32)

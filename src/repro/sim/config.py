@@ -10,7 +10,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Optional
 
-from .topology import ClosParams
+from .topology import ClosParams, FabricParams
 from .trace.spec import TraceSpec
 
 
@@ -133,7 +133,9 @@ PRESETS = {p.name: p for p in
 class SimConfig:
     proto: ProtoConfig
     timing: TimingParams = TimingParams()
-    clos: ClosParams = ClosParams()
+    # the fabric: a leaf-spine's ClosParams or a fat-tree's FatTreeParams
+    # (a folded Clos too)
+    clos: FabricParams = ClosParams()
     bloom_stages: int = 4
     bloom_stage_bits: int = 256
     ft_buckets: int = 8192

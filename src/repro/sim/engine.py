@@ -28,9 +28,10 @@ traced operand bundles (`FlowOperands` here, `topology.TopoOperands`), and
 both padding contracts (phantom flows, phantom ports/switches/servers) that
 let `sim/sweep.py` vmap a whole topology x workload x seed grid through one
 compiled program. Only `TopoDims` (port/server/switch counts, padded
-wire-ring length `prop_max`) and the protocol/timing configuration remain
-compile-time constants; the link propagation delay itself is the traced
-`TopoOperands.prop_ticks` modulus, so mixed-latency grids share a program.
+wire-ring length `prop_max`, route width `hops`) and the protocol/timing
+configuration remain compile-time constants; the link propagation delay
+itself is the traced `TopoOperands.prop_ticks` modulus, so mixed-latency
+grids share a program.
 """
 from __future__ import annotations
 
@@ -72,7 +73,8 @@ LANE_AXIS = "lane"
 class FlowOperands(NamedTuple):
     """Per-flow metadata fed to the jitted step as traced operands.
 
-    Shapes are static per compiled program: (F,) / (F, MAX_HOPS) / (F, S).
+    Shapes are static per compiled program: (F,) / (F, H) / (F, S), with
+    H = `TopoDims.hops`, the route width (shorter routes padded with -1).
     `sim/sweep.py` stacks these along a leading batch axis and vmaps the
     step, so one compilation serves a whole seed/load grid. Routes name
     egress ports of the lane's own fabric, so the per-flow routing table

@@ -321,7 +321,8 @@ def execute(plan: ExecPlan, topos: Sequence, flowsets: Sequence, cfg, *,
             fsets += [flowsets[0]] * (width - n_take)
             tps = list(topos[lo:lo + n_take])
             tps += [topos[0]] * (width - n_take)
-            return (sweep.stack_operands(fsets, cfg, plan.f_max),
+            return (sweep.stack_operands(fsets, cfg, plan.f_max,
+                                         plan.dims.hops),
                     sweep.stack_topos(tps, cfg, plan.dims))
 
     def launch(idx: int, lo: int, n_real: int):

@@ -32,12 +32,14 @@ import jax.numpy as jnp
 from ...core import bloom
 from .ctx import I32, PhaseEnv, StepCtx, hop_of_port, lane_any
 
+# popping queues one resume pass handles; a tau with more pops takes more
+# passes, so the pop's cost follows the pops and not the fabric's queues
+RESUME_CHUNK = 128
+
 
 def control(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
     pc = env.cfg.proto
-    P, Q, F, PLCAP = env.P, env.Q, env.F, env.PLCAP
-    p_ar = jnp.arange(P)
-    q_ar = jnp.arange(Q)
+    F = env.F
 
     is_tau = (ctx.t % env.TAU) == 0
     bloom_counts, bloom_mid, bloom_rx = (st.bloom_counts, st.bloom_mid,
@@ -53,29 +55,8 @@ def control(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
             do_pop = pending & below            # ablation: no throttling
 
         def resume(carry):
-            pl_head, f_paused, bloom_counts = carry
             with jax.named_scope("control.resume"):
-                cand = jnp.take_along_axis(
-                    pl, (pl_head % PLCAP)[..., None], axis=2)[..., 0]  # (P,Q)
-                cand_f = jnp.maximum(cand, 0)
-                cand_hop = hop_of_port(ops.routes, cand_f,
-                                       p_ar[:, None])                 # (P,Q)
-                valid = (do_pop & (cand >= 0)
-                         & (st.f_q[cand_f, cand_hop] == q_ar[None, :])
-                         & st.f_paused[cand_f, cand_hop]
-                         & (st.f_cnt[cand_f, cand_hop] > 0))
-                pl_head = pl_head + do_pop.astype(I32)
-                # unpause (scatter with OOB-drop for invalid lanes)
-                flat_f = jnp.where(valid, cand_f, F).reshape(-1)
-                flat_hop = cand_hop.reshape(-1)
-                f_paused = f_paused.at[flat_f, flat_hop].set(False)
-                up_port = ops.routes[cand_f.reshape(-1),
-                                     jnp.maximum(cand_hop.reshape(-1) - 1, 0)]
-                bloom_counts = bloom.add_batch(
-                    bloom_counts, jnp.maximum(up_port, 0),
-                    ops.fpos[cand_f.reshape(-1)],
-                    jnp.where(valid.reshape(-1), -1, 0))
-            return pl_head, f_paused, bloom_counts
+                return _pop_resumes(env, st, ops, do_pop, *carry)
 
         # With nothing to pop `resume` is the identity, so a tick on which
         # no lane pops skips it without changing a bit (with `resume_limit`,
@@ -110,3 +91,44 @@ def control(env: PhaseEnv, st, ops, topo, ctx: StepCtx) -> StepCtx:
     return ctx._replace(bloom_counts=bloom_counts, bloom_mid=bloom_mid,
                         bloom_rx=bloom_rx, pl=pl, pl_head=pl_head,
                         f_paused=f_paused, sfc_ring=sfc_ring, n_sfc=n_sfc)
+
+
+def _pop_resumes(env: PhaseEnv, st, ops, do_pop, pl_head, f_paused,
+                 bloom_counts):
+    """Pop the head of each pause list that `do_pop` marks: clear that
+    flow's pause bit and take it out of the upstream counting Bloom filter.
+    The popping queues are taken RESUME_CHUNK at a time, so a pass costs
+    the pops and not the fabric's P x Q queues."""
+    P, Q, F, PLCAP = env.P, env.Q, env.F, env.PLCAP
+    rank = jnp.cumsum(do_pop.reshape(-1).astype(I32))           # (P*Q,)
+    n_pop = rank[-1]
+    K = min(RESUME_CHUNK, P * Q)
+
+    def chunk(c):
+        # the loop body traces outside the caller's scope
+        with jax.named_scope("control.resume"):
+            start, f_paused, bloom_counts = c
+            k = start + jnp.arange(K, dtype=I32)
+            # the (k+1)-th popping queue: the count of ranks <= k
+            e = jnp.minimum((rank[None, :] <= k[:, None]).sum(1, dtype=I32),
+                            P * Q - 1)                              # (K,)
+            p, q = e // Q, e % Q
+            cand = st.pl[p, q, pl_head[p, q] % PLCAP]
+            cand_f = jnp.maximum(cand, 0)
+            cand_hop = hop_of_port(ops.routes, cand_f, p)
+            valid = ((k < n_pop) & (cand >= 0)
+                     & (st.f_q[cand_f, cand_hop] == q)
+                     & st.f_paused[cand_f, cand_hop]
+                     & (st.f_cnt[cand_f, cand_hop] > 0))
+            # unpause (scatter with OOB-drop for invalid entries)
+            f_paused = f_paused.at[jnp.where(valid, cand_f, F),
+                                   cand_hop].set(False)
+            up_port = ops.routes[cand_f, jnp.maximum(cand_hop - 1, 0)]
+            bloom_counts = bloom.add_batch(
+                bloom_counts, jnp.maximum(up_port, 0), ops.fpos[cand_f],
+                jnp.where(valid, -1, 0))
+            return start + K, f_paused, bloom_counts
+
+    _, f_paused, bloom_counts = jax.lax.while_loop(
+        lambda c: c[0] < n_pop, chunk, (jnp.int32(0), f_paused, bloom_counts))
+    return pl_head + do_pop.astype(I32), f_paused, bloom_counts

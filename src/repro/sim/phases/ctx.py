@@ -17,7 +17,7 @@ from ...core import bloom
 from ...kernels.bfc_step import ops as kernel_ops
 from ...kernels.bfc_step.ref import pause_threshold
 from ..config import SimConfig
-from ..topology import MAX_HOPS, TopoDims
+from ..topology import TopoDims
 
 I32 = jnp.int32
 BIG = np.int32(1 << 20)  # large-but-packable sentinel for priority keys
@@ -67,7 +67,8 @@ class PhaseEnv(NamedTuple):
 
     @property
     def H(self) -> int:
-        return MAX_HOPS
+        # route width: every lane's routes are padded to it with -1
+        return self.dims.hops
 
     @property
     def S(self) -> int:
@@ -85,7 +86,7 @@ def make_env(dims: TopoDims, cfg: SimConfig, n_flows: int,
     # actual hop counts and of each lane's true prop_ticks: a ring is a
     # pure delay line, so oversizing it never changes when feedback lands)
     return PhaseEnv(cfg=cfg, dims=dims, F=int(n_flows),
-                    RING=MAX_HOPS * dims.prop_max + 2,
+                    RING=dims.hops * dims.prop_max + 2,
                     RRING=cfg.timing.rto_ticks + 1,
                     bparams=bloom.BloomParams(cfg.bloom_stages,
                                               cfg.bloom_stage_bits),

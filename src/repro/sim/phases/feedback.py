@@ -13,7 +13,7 @@ lands this tick's row of the `sfc_ring` pause-signal delay line into the
 per-flow `sfc_until` deadline that gates `nic_tx`.
 
 The feedback rings are delay lines of static length `env.RING`
-(= `MAX_HOPS * dims.prop_max + 2`, the worst case over a batch's lanes):
+(= `dims.hops * dims.prop_max + 2`, the worst case over a batch's lanes):
 `arrivals` scatters at `(t + delay) % RING` with a delay derived from the
 lane's *traced* `prop_ticks`, and this phase drains row `t % RING`, so an
 entry lands exactly `delay` ticks after it was scheduled no matter how far

@@ -55,16 +55,18 @@ from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .config import PRESETS, SimConfig
-from .topology import ClosParams, Topology, build, build_cached
+from .topology import (ClosParams, FabricParams, FatTreeParams, Topology,
+                       build, build_cached)
 
 
-def topo_tag(clos: ClosParams) -> str:
+def topo_tag(clos: FabricParams) -> str:
     """Short label component identifying a fabric in multi-topology grids.
 
     Includes the link delay so fabrics that differ only in `prop_ticks`
     (the rtt_sweep / cross_dc_latency axes) still get distinct labels."""
-    return (f"t{clos.n_tor}x{clos.n_spine}s{clos.n_servers}"
-            f"b{clos.switch_buffer_pkts}p{clos.prop_ticks}")
+    shape = (f"k{clos.k}" if isinstance(clos, FatTreeParams)
+             else f"t{clos.n_tor}x{clos.n_spine}s{clos.n_servers}")
+    return f"{shape}b{clos.switch_buffer_pkts}p{clos.prop_ticks}"
 
 
 @dataclass(frozen=True)
@@ -92,7 +94,7 @@ class Scenario:
     incast_kb_per_flow: int = 0
     # optional topology axis: each entry becomes a batch lane (padded to a
     # common TopoDims by sim.sweep); empty = the caller/driver's fabric.
-    topologies: Tuple[ClosParams, ...] = ()
+    topologies: Tuple[FabricParams, ...] = ()
     locality: float = 0.0
     long_lived: int = 0
     long_lived_pkts: int = 1 << 24
@@ -118,8 +120,8 @@ class Scenario:
             n *= k
         return n
 
-    def topology_axis(self, default: Optional[ClosParams]
-                      ) -> Tuple[ClosParams, ...]:
+    def topology_axis(self, default: Optional[FabricParams]
+                      ) -> Tuple[FabricParams, ...]:
         if self.topologies:
             return self.topologies
         return (default if default is not None else ClosParams(),)
@@ -213,7 +215,7 @@ def names() -> List[str]:
     return sorted(SCENARIOS)
 
 
-def run(name_or_scenario, clos: Optional[ClosParams] = None,
+def run(name_or_scenario, clos: Optional[FabricParams] = None,
         n_flows: Optional[int] = None, drain: Optional[int] = None,
         unroll: int = 1, max_batch_bytes: Optional[int] = None,
         devices: Optional[Sequence] = None, auto_budget: bool = True,
@@ -222,8 +224,9 @@ def run(name_or_scenario, clos: Optional[ClosParams] = None,
         n_ticks: Optional[int] = None):
     """Run one registry scenario through the batched sweep subsystem.
 
-    `clos` sets the fabric for scenarios without their own `topologies`
-    axis (scenarios WITH one pin their fabrics absolutely). Execution
+    `clos` sets the fabric (a leaf-spine's `ClosParams`, the default, or a
+    `FatTreeParams`) for scenarios without their own `topologies` axis
+    (scenarios WITH one pin their fabrics absolutely). Execution
     placement — chunk width, multi-device sharding, chunk spooling — is
     planned per protocol group by `sim.exec` (`devices`, `auto_budget`,
     `max_batch_bytes`, `store` pass through to its planner/dispatcher;

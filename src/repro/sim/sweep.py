@@ -18,10 +18,16 @@ program). Padded "phantom" flows are inert by construction:
 * ``size_pkts = 0`` — even if started it would have nothing to send;
 * ``routes = -1`` everywhere — a phantom is never looked up by any hop.
 
+Routes are also padded to the batch's route width ``TopoDims.hops`` with
+-1 columns: a leaf-spine lane (4 hops) in a batch with a fat-tree (6) never
+takes its extra hops, since a packet leaves the fabric where its route
+ends.
+
 Topologies in a batch are likewise padded to a common ``TopoDims`` (max
-ports / servers / switches / ``prop_max``, the padded wire-ring length —
-each lane's wires wrap at its own traced ``TopoOperands.prop_ticks``
-modulus, so link latency rides the batch axis too). Phantom
+ports / servers / switches / ``hops`` / ``prop_max``, the padded wire-ring
+length — each lane's wires wrap at its own traced
+``TopoOperands.prop_ticks`` modulus, so link latency rides the batch axis
+too). Phantom
 ports/switches/servers are inert by the mirror argument:
 no route names a phantom port, so it never holds occupancy and never
 transmits; phantom servers never source flows, so their NIC lane never wins
@@ -59,7 +65,7 @@ chunk is padded with repeats of lane 0, padded results dropped).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -70,8 +76,8 @@ from . import engine, metrics
 from .config import SimConfig
 from .engine import FlowOperands, SimState
 from .exec.dispatch import span
-from .topology import (MAX_HOPS, TopoDims, TopoOperands, Topology,
-                       build_cached, pack_topo)
+from .topology import (TopoDims, TopoOperands, Topology, build_cached,
+                       pack_topo)
 from .workload import FlowSet
 
 # Default padding quantum for F_max: coarse enough that ragged grids share
@@ -97,18 +103,29 @@ _PER_PORT_AXIS0 = {
 _PER_SERVER_AXIS0 = {"nic_ptr"}
 _PER_SERVER_AXIS1 = {"d_q", "d_cnt"}
 _PER_SWITCH_AXIS0 = {"bucket_cnt"}
+# ... and the per-(flow, hop) tables, trimmed back to the fabric's own
+# route width `TopoDims.hops` ...
+_PER_HOP_AXIS1 = {"f_q", "f_cnt", "f_paused"}
 # ... and the leaves whose shapes scale with the padded wire-ring length
 # `TopoDims.prop_max`: the wires themselves (axis 1 = PROP_MAX) and the
-# feedback delay lines (axis 0 = MAX_HOPS * prop_max + 2).
+# feedback delay lines (axis 0 = hops * prop_max + 2).
 _PER_PROP_AXIS1 = {"wire_f", "wire_hop"}
 _FB_RING_AXIS0 = {"ack_ring", "mark_ring", "u_ring", "sfc_ring"}
 
 
-def pad_flowset(flows: FlowSet, f_max: int) -> FlowSet:
-    """Append inert phantom flows until the set holds `f_max` flows."""
+def pad_flowset(flows: FlowSet, f_max: int,
+                hops: Optional[int] = None) -> FlowSet:
+    """Append inert phantom flows until the set holds `f_max` flows, and
+    -1 route columns until routes are `hops` wide (default: as they are)."""
     pad = f_max - flows.n_flows
-    if pad < 0:
-        raise ValueError(f"f_max={f_max} < n_flows={flows.n_flows}")
+    routes = np.asarray(flows.routes, np.int32)
+    wide = (hops or routes.shape[1]) - routes.shape[1]
+    if pad < 0 or wide < 0:
+        raise ValueError(f"f_max={f_max}, hops={hops} < the set's "
+                         f"{flows.n_flows} flows x {routes.shape[1]} hops")
+    if wide:
+        routes = np.pad(routes, ((0, 0), (0, wide)), constant_values=-1)
+        flows = replace(flows, routes=routes)
     if pad == 0:
         return flows
     return FlowSet(
@@ -121,8 +138,8 @@ def pad_flowset(flows: FlowSet, f_max: int) -> FlowSet:
         arrival_tick=np.concatenate(
             [np.asarray(flows.arrival_tick, np.int32),
              np.full(pad, engine.PHANTOM_ARRIVAL, np.int32)]),
-        routes=np.concatenate([np.asarray(flows.routes, np.int32),
-                               np.full((pad, MAX_HOPS), -1, np.int32)]),
+        routes=np.concatenate([routes, np.full((pad, routes.shape[1]), -1,
+                                               np.int32)]),
         ideal_fct=np.concatenate([np.asarray(flows.ideal_fct, np.int32),
                                   np.ones(pad, np.int32)]),
         fid=np.concatenate([np.asarray(flows.fid, np.int32),
@@ -139,9 +156,11 @@ def padded_count(flowsets: Sequence[FlowSet],
 
 
 def stack_operands(flowsets: Sequence[FlowSet], cfg: SimConfig,
-                   f_max: int) -> FlowOperands:
-    """Pad every FlowSet to `f_max` and stack operands on a batch axis."""
-    packed = [engine.pack_flows(pad_flowset(f, f_max), cfg)
+                   f_max: int, hops: Optional[int] = None) -> FlowOperands:
+    """Pad every FlowSet to `f_max` flows and `hops` route columns (default:
+    the widest set's) and stack operands on a batch axis."""
+    hops = hops or max(np.shape(f.routes)[1] for f in flowsets)
+    packed = [engine.pack_flows(pad_flowset(f, f_max, hops), cfg)
               for f in flowsets]
     return FlowOperands(*[jnp.stack(leaves) for leaves in zip(*packed)])
 
@@ -181,10 +200,10 @@ def lane_state_bytes(dims: TopoDims, cfg: SimConfig, n_flows: int,
 
     Because the measurement walks the shapes `make_step(dims, ...)` would
     allocate, it automatically includes the `dims.prop_max`-padded wire
-    rings (P x prop_max x 2) and feedback delay lines
-    ((4 * prop_max + 2) x F x 3): a mixed-latency batch padded to a long
-    wire bills every lane at the padded size, and the exec planner's chunk
-    width shrinks accordingly."""
+    rings (P x prop_max x 2), the (F, dims.hops) hop tables and the
+    feedback delay lines ((hops * prop_max + 2) x F x 4): a batch padded to
+    a long wire or a wide route bills every lane at the padded size, and
+    the exec planner's chunk width shrinks accordingly."""
     init_state, _ = engine.make_step(dims, engine.static_cfg(cfg), n_flows)
     leaves = jax.tree_util.tree_leaves(jax.eval_shape(init_state))
     state = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
@@ -195,18 +214,21 @@ def lane_state_bytes(dims: TopoDims, cfg: SimConfig, n_flows: int,
 
 def trim_state(state: SimState, n_flows: int,
                dims: Optional[TopoDims] = None) -> SimState:
-    """Trim the per-flow — and, given `dims`, per-port/server/switch/prop —
-    leaves of an (unbatched) SimState back to the workload's true F and the
-    fabric's true shapes, dropping the phantom tails a padded run carries.
+    """Trim the per-flow — and, given `dims`, per-port/server/switch/hop/
+    prop — leaves of an (unbatched) SimState back to the workload's true F
+    and the fabric's true shapes, dropping the phantom tails a padded run
+    carries.
 
-    Wire rings are trimmed to `dims.prop_max` slots (slots beyond a lane's
-    true delay are never-touched padding). The feedback delay lines are
-    *re-indexed* rather than sliced: two runs padded to different
-    `prop_max` store the same pending feedback at different absolute rows
-    (the ring length is the wrap modulus), so rows are rotated to
-    offset-from-`state.t` order and cut at the fabric's own worst-case
-    delay — after which a prop-padded run is leaf-for-leaf comparable with
-    its unpadded serial twin."""
+    Hop tables are trimmed to `dims.hops` columns and wire rings to
+    `dims.prop_max` slots (hops a route never takes and slots beyond a
+    lane's true delay are never-touched padding). The feedback delay lines
+    are *re-indexed* rather than sliced: two runs padded to different
+    `hops * prop_max` store the same pending feedback at different
+    absolute rows (the ring length is the wrap modulus), so rows are
+    rotated to offset-from-`state.t` order and cut at the fabric's own
+    worst-case delay, `dims.hops * dims.prop_max + 2` rows — after which a
+    padded run is leaf-for-leaf comparable with its unpadded serial
+    twin."""
     t = int(np.asarray(state.t))
     out = {}
     for name, leaf in state._asdict().items():
@@ -224,14 +246,17 @@ def trim_state(state: SimState, n_flows: int,
                 v = v[:dims.n_switches]
             if name in _PER_SERVER_AXIS1:
                 v = v[:, :dims.n_servers]
+            if name in _PER_HOP_AXIS1:
+                v = v[:, :dims.hops]
             if name in _PER_PROP_AXIS1:
                 v = v[:, :dims.prop_max]
             elif name in _FB_RING_AXIS0:
-                ring = MAX_HOPS * dims.prop_max + 2
+                ring = dims.hops * dims.prop_max + 2
                 if ring > v.shape[0]:
                     raise ValueError(
-                        f"trim_state: dims.prop_max={dims.prop_max} "
-                        f"implies a {ring}-row feedback ring but the "
+                        f"trim_state: dims.hops={dims.hops} and "
+                        f"prop_max={dims.prop_max} imply a {ring}-row "
+                        "feedback ring but the "
                         f"state holds {v.shape[0]} rows — pass the "
                         "fabric's own TopoDims, not a batch union")
                 v = v[(t + np.arange(ring)) % v.shape[0]]
@@ -290,10 +315,14 @@ def run_batch(topo: Union[Topology, Sequence[Topology]],
     if plan is None:
         budget = (max_batch_bytes if max_batch_bytes is not None
                   else ("auto" if auto_budget else None))
-        with span("repro.exec.plan", lanes=K):
+        # the shapes that key the program, and the state one lane holds
+        with span("repro.exec.plan", lanes=K, ports=dims.n_ports,
+                  servers=dims.n_servers, switches=dims.n_switches,
+                  hops=dims.hops, flows=f_max) as sp:
             plan = exec_.plan(dims, cfg, f_max, n_ticks, K,
                               devices=devices, budget=budget, unroll=unroll,
                               early_exit=early_exit)
+            sp.counts["lane_bytes"] = plan.per_lane_bytes
     return exec_.execute(plan, topos, flowsets, cfg, store=store,
                          tag=cfg.proto.name, resume=resume)
 
@@ -311,9 +340,10 @@ class CaseResult:
 
 
 def _case_topo(cfg: SimConfig, default: Topology) -> Topology:
-    """The fabric a case runs on: its own `cfg.clos` (the topology is part
-    of the per-case configuration now), materialized through the build
-    cache; `default` is reused when it already matches."""
+    """The fabric a case runs on: its own `cfg.clos` (a leaf-spine's
+    `ClosParams` or a fat-tree's `FatTreeParams`; the topology is part of
+    the per-case configuration), materialized through the build cache;
+    `default` is reused when it already matches."""
     if cfg.clos == default.params:
         return default
     return build_cached(cfg.clos)

@@ -1,10 +1,23 @@
-"""Clos topology + routing for the packet simulator (paper §4.1).
-
-The paper's evaluation topology: 128 leaf servers, 8 ToRs (16 servers each),
-8 spines, all links 100 Gbps, 2:1 oversubscription, 1 us per-link propagation.
+"""Fabrics of the packet simulator: one port graph, any topology.
 
 Everything that transmits is an *egress port*. Ports are flattened into one
-global index space so the whole network updates as dense arrays:
+global index space so the whole network updates as dense arrays: the
+server NIC uplinks first, `[0, n_servers)`, then the switches' ports,
+switch by switch. A fabric is three per-port tables and a route builder
+(`Topology`):
+
+* `port_switch[p]`: the switch owning port p (-1 for a NIC);
+* `port_is_nic[p]`;
+* `feeds[p]`: the switch whose buffer a packet sent from p enters (PFC and
+  buffer accounting), -1 where p delivers to a server;
+* `routes(src, dst, fid)`: the sequence of egress ports each flow is
+  *transmitted from*, `hops` columns wide, -1 padded. The hop after the
+  last valid port is delivery at the destination server.
+
+`build(params)` makes one from either fabric's parameters:
+
+`ClosParams`, the paper's two-tier leaf-spine (section 6: 128 servers, 8
+ToRs of 16 servers, 8 spines, 2:1 oversubscription), `hops = 4`:
 
   [0, n_servers)                         server NIC uplink ports
   [nic_end, nic_end + n_tor*ports_tor)   ToR ports: per ToR, first
@@ -12,21 +25,39 @@ global index space so the whole network updates as dense arrays:
                                          servers) then `n_spine` up-ports
   [tor_end, tor_end + n_spine*n_tor)     spine down-ports (to each ToR)
 
-A flow's route is the sequence of egress ports it is *transmitted from*:
   inter-ToR: [src NIC, src ToR up-port(spine s), spine s down-port(dst ToR),
               dst ToR down-port(dst server)]
   intra-ToR: [src NIC, dst ToR down-port(dst server), -1, -1]
+
+`FatTreeParams`, Al-Fares et al.'s k-ary three-tier fat-tree (SIGCOMM 2008,
+section 3: k pods of k/2 edge and k/2 aggregation switches, (k/2)^2 cores,
+k^3/4 hosts, k ports a switch, full bisection), `hops = 6`. With h = k/2:
+switches 0..k*h-1 are edges, the next k*h aggregations, then h*h cores;
+pod p holds edges h*p..h*p+h-1 and the aggregations at the same offsets.
+Every switch has k ports, numbered after the NICs switch by switch: an
+edge's h down-ports (its hosts, in host order) then h up-ports (its pod's
+aggregations, in order); an aggregation's h down-ports (its pod's edges)
+then h up-ports, up-port i of aggregation j going to core h*j + i; a
+core's k down-ports, one per pod.
+
+  same edge:  [src NIC, edge down(dst)]
+  same pod:   [src NIC, edge up j, agg j down(dst edge), edge down(dst)]
+  other pod:  [src NIC, edge up j, agg j up i, core h*j+i down(dst pod),
+               agg j down(dst edge), edge down(dst)]
+
+Flow-level ECMP picks j and i by two independent hashes of the flow id
+(the leaf-spine's spine by the first).
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import NamedTuple
+from typing import Callable, NamedTuple, Union
 
 import jax.numpy as jnp
 import numpy as np
 
-MAX_HOPS = 4
+from ..core.hashing import ecmp_choice
 
 
 @dataclass(frozen=True)
@@ -48,89 +79,183 @@ class ClosParams:
     def ports_per_tor(self) -> int:
         return self.servers_per_tor + self.n_spine
 
+    # -- what the workload generator normalises load over ---------------------
+    @property
+    def servers_per_rack(self) -> int:
+        return self.servers_per_tor
 
-@dataclass
+    @property
+    def core_links(self) -> int:
+        """ToR-to-spine links, each one packet a tick."""
+        return self.n_tor * self.n_spine
+
+    @property
+    def core_share(self) -> float:
+        """Share of uniformly drawn flows whose path crosses the core."""
+        return 1.0 - 1.0 / self.n_tor
+
+
+@dataclass(frozen=True)
+class FatTreeParams:
+    """A k-ary fat-tree (Al-Fares, Loukissas and Vahdat, SIGCOMM 2008)."""
+    k: int = 8
+    prop_ticks: int = 12
+    switch_buffer_pkts: int = 12288
+
+    def __post_init__(self):
+        if self.k < 2 or self.k % 2:
+            raise ValueError(f"a fat-tree needs an even k >= 2, got {self.k}")
+
+    @property
+    def n_servers(self) -> int:
+        return self.k ** 3 // 4
+
+    @property
+    def servers_per_rack(self) -> int:
+        return self.k // 2
+
+    @property
+    def core_links(self) -> int:
+        """Aggregation-to-core links, each one packet a tick."""
+        return self.k ** 3 // 4
+
+    @property
+    def core_share(self) -> float:
+        """Share of uniformly drawn flows whose path crosses the core
+        (another pod)."""
+        return 1.0 - 1.0 / self.k
+
+
+FabricParams = Union[ClosParams, FatTreeParams]
+
+
+@dataclass(eq=False)
 class Topology:
-    params: ClosParams
+    """A fabric's port graph: per-port tables and its route builder.
+    Treated as immutable after `build`."""
+    params: FabricParams
     n_ports: int
     n_switches: int
-    # per-port metadata (numpy; baked into the jitted step as constants)
-    port_switch: np.ndarray      # switch id owning the port; -1 for NIC ports
-    port_is_nic: np.ndarray      # bool
-    # derived index helpers
-    nic_base: int = 0
-    tor_base: int = field(default=0)
-    spine_base: int = field(default=0)
+    hops: int                    # route width: transmissions on the longest path
+    port_switch: np.ndarray      # (n_ports,) owning switch; -1 for NIC ports
+    port_is_nic: np.ndarray      # (n_ports,) bool
+    feeds: np.ndarray            # (n_ports,) switch fed by the port; -1 = server
+    route: Callable = field(repr=False)
 
-    # ---- port index helpers -------------------------------------------------
-    def nic_port(self, server: np.ndarray) -> np.ndarray:
-        return np.asarray(server)
+    @property
+    def n_servers(self) -> int:
+        return self.params.n_servers
 
-    def tor_of_server(self, server: np.ndarray) -> np.ndarray:
-        return np.asarray(server) // self.params.servers_per_tor
-
-    def tor_down_port(self, tor, server) -> np.ndarray:
-        local = np.asarray(server) % self.params.servers_per_tor
-        return self.tor_base + np.asarray(tor) * self.params.ports_per_tor + local
-
-    def tor_up_port(self, tor, spine) -> np.ndarray:
-        return (self.tor_base + np.asarray(tor) * self.params.ports_per_tor
-                + self.params.servers_per_tor + np.asarray(spine))
-
-    def spine_down_port(self, spine, tor) -> np.ndarray:
-        return self.spine_base + np.asarray(spine) * self.params.n_tor + np.asarray(tor)
+    def routes(self, src, dst, fid) -> np.ndarray:
+        """(n_flows, hops) int32 egress port ids of each flow's path, -1
+        padded; ECMP choices hash the flow id `fid`."""
+        return self.route(np.asarray(src), np.asarray(dst),
+                          np.asarray(fid, np.int32))
 
 
-def build(params: ClosParams) -> Topology:
-    n_nic = params.n_servers
-    n_tor_ports = params.n_tor * params.ports_per_tor
-    n_spine_ports = params.n_spine * params.n_tor
-    n_ports = n_nic + n_tor_ports + n_spine_ports
-    n_switches = params.n_tor + params.n_spine
+def _ecmp(fid: np.ndarray, n_paths: int, level: int = 0) -> np.ndarray:
+    return np.asarray(ecmp_choice(jnp.asarray(fid), n_paths, level))
+
+
+def _leaf_spine(p: ClosParams) -> Topology:
+    per, n_tor, n_spine = p.servers_per_tor, p.n_tor, p.n_spine
+    ppt = p.ports_per_tor
+    tor_base = p.n_servers
+    spine_base = tor_base + n_tor * ppt
+    n_ports = spine_base + n_spine * n_tor
+
+    def tor_down(tor, server):
+        return tor_base + tor * ppt + server % per
+
+    def tor_up(tor, spine):
+        return tor_base + tor * ppt + per + spine
+
+    def spine_down(spine, tor):
+        return spine_base + spine * n_tor + tor
 
     port_switch = np.full(n_ports, -1, np.int32)
-    port_is_nic = np.zeros(n_ports, bool)
-    port_is_nic[:n_nic] = True
+    port_switch[tor_base:spine_base] = np.repeat(np.arange(n_tor), ppt)
+    port_switch[spine_base:] = n_tor + np.repeat(np.arange(n_spine), n_tor)
+    feeds = np.full(n_ports, -1, np.int32)
+    feeds[:p.n_servers] = np.arange(p.n_servers) // per   # NIC -> its ToR
+    tor, sp = np.meshgrid(np.arange(n_tor), np.arange(n_spine),
+                          indexing="ij")
+    feeds[tor_up(tor, sp)] = n_tor + sp                   # ToR up -> spine
+    feeds[spine_down(sp, tor)] = tor                      # spine down -> ToR
+    # ToR down-ports feed servers: stay -1
 
-    tor_base = n_nic
-    spine_base = n_nic + n_tor_ports
-    for tor in range(params.n_tor):
-        lo = tor_base + tor * params.ports_per_tor
-        port_switch[lo:lo + params.ports_per_tor] = tor
-    for spine in range(params.n_spine):
-        lo = spine_base + spine * params.n_tor
-        port_switch[lo:lo + params.n_tor] = params.n_tor + spine
+    def route(src, dst, fid):
+        routes = np.full((src.shape[0], 4), -1, np.int32)
+        s_tor, d_tor = src // per, dst // per
+        routes[:, 0] = src
+        intra = s_tor == d_tor
+        routes[intra, 1] = tor_down(d_tor[intra], dst[intra])
+        inter = ~intra
+        spine = _ecmp(fid, n_spine)[inter]
+        routes[inter, 1] = tor_up(s_tor[inter], spine)
+        routes[inter, 2] = spine_down(spine, d_tor[inter])
+        routes[inter, 3] = tor_down(d_tor[inter], dst[inter])
+        return routes
 
-    topo = Topology(params=params, n_ports=n_ports, n_switches=n_switches,
-                    port_switch=port_switch, port_is_nic=port_is_nic)
-    topo.tor_base = tor_base
-    topo.spine_base = spine_base
-    return topo
+    return Topology(params=p, n_ports=n_ports, n_switches=n_tor + n_spine,
+                    hops=4, port_switch=port_switch,
+                    port_is_nic=port_switch < 0, feeds=feeds, route=route)
 
 
-def routes_for_flows(topo: Topology, src: np.ndarray, dst: np.ndarray,
-                     spine_choice: np.ndarray) -> np.ndarray:
-    """Vectorized route computation.
+def _fat_tree(p: FatTreeParams) -> Topology:
+    k, h = p.k, p.k // 2
+    n_host, n_edge, n_core = p.n_servers, k * h, h * h
+    agg0, core0 = n_edge, 2 * n_edge
+    n_sw = 2 * n_edge + n_core
+    n_ports = n_host + n_sw * k
 
-    Returns (n_flows, MAX_HOPS) int32 of egress port ids, -1 padded. The hop
-    *after* the last valid port is delivery at the destination server.
-    """
-    src = np.asarray(src); dst = np.asarray(dst)
-    n = src.shape[0]
-    routes = np.full((n, MAX_HOPS), -1, np.int32)
-    s_tor = topo.tor_of_server(src)
-    d_tor = topo.tor_of_server(dst)
-    routes[:, 0] = topo.nic_port(src)
-    intra = s_tor == d_tor
-    # intra-ToR: NIC -> ToR down-port to dst
-    routes[intra, 1] = topo.tor_down_port(d_tor[intra], dst[intra])
-    # inter-ToR: NIC -> ToR up (spine) -> spine down (dst ToR) -> ToR down (dst)
-    inter = ~intra
-    sp = np.asarray(spine_choice)[inter] % topo.params.n_spine
-    routes[inter, 1] = topo.tor_up_port(s_tor[inter], sp)
-    routes[inter, 2] = topo.spine_down_port(sp, d_tor[inter])
-    routes[inter, 3] = topo.tor_down_port(d_tor[inter], dst[inter])
-    return routes
+    def port(switch, local):
+        return n_host + switch * k + local
+
+    port_switch = np.full(n_ports, -1, np.int32)
+    port_switch[n_host:] = np.repeat(np.arange(n_sw), k)
+    feeds = np.full(n_ports, -1, np.int32)
+    feeds[:n_host] = np.arange(n_host) // h               # NIC -> its edge
+    e = np.arange(n_edge)        # edge e, or aggregation agg0 + e, of pod e // h
+    c = np.arange(n_core)
+    for x in range(h):
+        feeds[port(e, h + x)] = agg0 + (e // h) * h + x           # edge up
+        feeds[port(agg0 + e, x)] = (e // h) * h + x               # agg down
+        feeds[port(agg0 + e, h + x)] = core0 + (e % h) * h + x    # agg up
+    for pod in range(k):
+        feeds[port(core0 + c, pod)] = agg0 + pod * h + c // h     # core down
+    # edge down-ports feed servers: stay -1
+
+    def route(src, dst, fid):
+        routes = np.full((src.shape[0], 6), -1, np.int32)
+        j, i = _ecmp(fid, h, 0), _ecmp(fid, h, 1)
+        s_edge, d_edge = src // h, dst // h
+        s_pod, d_pod = s_edge // h, d_edge // h
+        last = port(d_edge, dst % h)
+        agg_down = port(agg0 + d_pod * h + j, d_edge % h)
+        routes[:, 0] = src
+        edge = s_edge == d_edge
+        pod = (s_pod == d_pod) & ~edge
+        far = s_pod != d_pod
+        routes[edge, 1] = last[edge]
+        routes[~edge, 1] = port(s_edge, h + j)[~edge]
+        routes[pod, 2] = agg_down[pod]
+        routes[pod, 3] = last[pod]
+        routes[far, 2] = port(agg0 + s_pod * h + j, h + i)[far]
+        routes[far, 3] = port(core0 + h * j + i, d_pod)[far]
+        routes[far, 4] = agg_down[far]
+        routes[far, 5] = last[far]
+        return routes
+
+    return Topology(params=p, n_ports=n_ports, n_switches=n_sw, hops=6,
+                    port_switch=port_switch, port_is_nic=port_switch < 0,
+                    feeds=feeds, route=route)
+
+
+def build(params: FabricParams) -> Topology:
+    if isinstance(params, FatTreeParams):
+        return _fat_tree(params)
+    return _leaf_spine(params)
 
 
 # Cached variant for callers that rebuild the same fabric repeatedly (the
@@ -153,23 +278,23 @@ class TopoDims(NamedTuple):
     `(P, prop_max)` arrays, but indexing wraps at the lane's own traced
     `TopoOperands.prop_ticks` modulus, so fabrics with different link
     delays still share one program (slots beyond a lane's true delay are
-    never touched)."""
+    never touched). `hops` is the route width H: the per-(flow, hop)
+    tables are (F, H) and the feedback rings hold H * prop_max + 2 rows;
+    a lane with shorter routes pads them with -1 (hops it never takes)."""
     n_ports: int
     n_servers: int
     n_switches: int
     prop_max: int
+    hops: int
 
     @classmethod
     def of(cls, topo: Topology) -> "TopoDims":
-        return cls(n_ports=topo.n_ports, n_servers=topo.params.n_servers,
+        return cls(n_ports=topo.n_ports, n_servers=topo.n_servers,
                    n_switches=topo.n_switches,
-                   prop_max=topo.params.prop_ticks)
+                   prop_max=topo.params.prop_ticks, hops=topo.hops)
 
     def union(self, other: "TopoDims") -> "TopoDims":
-        return TopoDims(n_ports=max(self.n_ports, other.n_ports),
-                        n_servers=max(self.n_servers, other.n_servers),
-                        n_switches=max(self.n_switches, other.n_switches),
-                        prop_max=max(self.prop_max, other.prop_max))
+        return TopoDims(*(max(a, b) for a, b in zip(self, other)))
 
 
 class TopoOperands(NamedTuple):
@@ -205,17 +330,12 @@ class TopoOperands(NamedTuple):
 
 def pack_topo(topo: Topology, *, infinite_buffer: bool = False,
               dims: "TopoDims | None" = None) -> TopoOperands:
-    """Derive the traced operand bundle for `topo`, padded to `dims`.
-
-    `feeds[p]` is the switch whose buffer grows when port p transmits (PFC
-    and buffer accounting): NIC -> its ToR, ToR up-port -> the spine, spine
-    down-port -> the ToR; ToR down-ports feed servers (-1)."""
+    """Derive the traced operand bundle for `topo`, padded to `dims`;
+    phantom ports feed no switch."""
     p0 = topo.params
     dims = dims or TopoDims.of(topo)
     P, NSW = dims.n_ports, dims.n_switches
-    if P < topo.n_ports or NSW < topo.n_switches \
-            or dims.n_servers < p0.n_servers \
-            or dims.prop_max < p0.prop_ticks:
+    if any(d < t for d, t in zip(dims, TopoDims.of(topo))):
         raise ValueError(f"dims {dims} smaller than topology")
 
     port_switch = np.full(P, -1, np.int32)
@@ -226,17 +346,8 @@ def pack_topo(topo: Topology, *, infinite_buffer: bool = False,
     port_valid[:topo.n_ports] = True
     switch_valid = np.zeros(NSW, bool)
     switch_valid[:topo.n_switches] = True
-
     feeds = np.full(P, -1, np.int32)
-    for s in range(p0.n_servers):
-        feeds[s] = s // p0.servers_per_tor                    # NIC -> its ToR
-    for tor in range(p0.n_tor):
-        for sp in range(p0.n_spine):
-            feeds[int(topo.tor_up_port(tor, sp))] = p0.n_tor + sp
-        # ToR down-ports feed servers: stays -1
-    for sp in range(p0.n_spine):
-        for tor in range(p0.n_tor):
-            feeds[int(topo.spine_down_port(sp, tor))] = tor
+    feeds[:topo.n_ports] = topo.feeds
 
     buffer_limit = (1 << 29) if infinite_buffer else p0.switch_buffer_pkts
     return TopoOperands(

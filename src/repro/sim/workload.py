@@ -12,7 +12,8 @@ industry workloads in Fig. 2 (the same sources as Homa [37]):
                       multi-MB flows.
 
 Arrivals: lognormal inter-arrival times (sigma = 2, paper §4.1) scaled so the
-offered load on the oversubscribed core equals the target. Source/destination
+offered load on the fabric's core links (the leaf-spine's ToR-to-spine links,
+a fat-tree's aggregation-to-core links) equals the target. Source/destination
 pairs uniform (or rack-local with probability `locality`, App. B). Incast:
 synchronized N-to-1 transfers of `incast_total_kb` aggregate, injected as a
 Poisson process sized to consume `incast_load` of capacity (§4).
@@ -24,9 +25,7 @@ from typing import Optional
 
 import numpy as np
 
-from .topology import Topology, routes_for_flows, ideal_fct_ticks
-from ..core.hashing import ecmp_choice
-import jax.numpy as jnp
+from .topology import Topology, ideal_fct_ticks
 
 # (size_in_KB, CDF-by-*count*) control points. Derived from the published
 # byte-weighted CDFs; interpolation is log-linear in size.
@@ -68,7 +67,7 @@ class FlowSet:
     dst: np.ndarray            # (F,)
     size_pkts: np.ndarray      # (F,)
     arrival_tick: np.ndarray   # (F,)
-    routes: np.ndarray         # (F, MAX_HOPS) egress port ids
+    routes: np.ndarray         # (F, H) egress port ids, H = the fabric's hops
     ideal_fct: np.ndarray      # (F,) ticks
     fid: np.ndarray            # (F,) 32-bit flow ids (for hashing)
     is_incast: np.ndarray      # (F,) bool
@@ -103,9 +102,11 @@ def generate(topo: Topology, wp: WorkloadParams, n_flows: int,
              long_lived: int = 0, long_lived_pkts: int = 1 << 30) -> FlowSet:
     """Generate `n_flows` background flows (+ optional incast + long-lived).
 
-    Load calibration: the core (ToR<->spine) carries the inter-rack fraction
-    of traffic over n_tor*n_spine links; we scale the mean inter-arrival so
-    that offered core load matches wp.load (paper's definition, §4 fn.4).
+    Load calibration: the core carries the share of traffic that crosses
+    it (`params.core_share`: another ToR on the leaf-spine, another pod on
+    a fat-tree) over `params.core_links` links; we scale the mean
+    inter-arrival so that offered core load matches wp.load (paper's
+    definition, §4 fn.4). Routes come from `topo.routes` (ECMP by flow id).
     """
     rng = np.random.default_rng(wp.seed)
     p = topo.params
@@ -113,10 +114,10 @@ def generate(topo: Topology, wp: WorkloadParams, n_flows: int,
     sizes = sample_sizes(rng, n_flows, wp.workload, wp.mtu_kb)
 
     # mean pkts/tick the network must carry to hit `load` on the core links:
-    # core capacity = n_tor * n_spine links * 1 pkt/tick; inter-rack fraction
-    # of flows = (1 - locality-adjusted intra fraction).
-    inter_frac = (1.0 - wp.locality) * (1.0 - 1.0 / p.n_tor) + 0.0
-    core_links = p.n_tor * p.n_spine
+    # core capacity = core_links * 1 pkt/tick; the share of flows crossing
+    # the core = (1 - locality) * core_share.
+    inter_frac = (1.0 - wp.locality) * p.core_share
+    core_links = p.core_links
     target_core_pkts_per_tick = wp.load * core_links
     mean_size = float(sizes.mean())
     # flows/tick so that inter-rack bytes/tick hits the target
@@ -137,10 +138,10 @@ def generate(topo: Topology, wp: WorkloadParams, n_flows: int,
         % p.n_servers
     if wp.locality > 0:
         local = rng.random(n_flows) < wp.locality
-        rack = src // p.servers_per_tor
-        off = rng.integers(1, p.servers_per_tor, local.sum())
-        dst[local] = rack[local] * p.servers_per_tor + \
-            (src[local] % p.servers_per_tor + off) % p.servers_per_tor
+        per = p.servers_per_rack
+        rack = src // per
+        off = rng.integers(1, per, local.sum())
+        dst[local] = rack[local] * per + (src[local] % per + off) % per
 
     is_incast = np.zeros(n_flows, bool)
     horizon = int(arrivals.max()) if n_flows else 0
@@ -174,7 +175,7 @@ def generate(topo: Topology, wp: WorkloadParams, n_flows: int,
     # ---- long-lived flows (Table 1 / Fig. 5 experiments) --------------------
     if long_lived > 0:
         ll_src = rng.integers(0, p.n_servers, long_lived)
-        ll_dst = (ll_src + p.servers_per_tor) % p.n_servers  # force inter-rack
+        ll_dst = (ll_src + p.servers_per_rack) % p.n_servers  # inter-rack
         src = np.concatenate([src, ll_src])
         dst = np.concatenate([dst, ll_dst])
         sizes = np.concatenate([sizes,
@@ -189,8 +190,7 @@ def generate(topo: Topology, wp: WorkloadParams, n_flows: int,
     fid = (np.arange(len(src), dtype=np.int64) * 2654435761 + wp.seed * 97 + 1) \
         % (1 << 31)
     fid = fid.astype(np.int32)
-    spine = np.asarray(ecmp_choice(jnp.asarray(fid), p.n_spine))
-    routes = routes_for_flows(topo, src, dst, spine)
+    routes = topo.routes(src, dst, fid)
     ideal = ideal_fct_ticks(routes, sizes.astype(np.int64), p.prop_ticks)
 
     return FlowSet(src=src.astype(np.int32), dst=dst.astype(np.int32),

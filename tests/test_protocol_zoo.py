@@ -28,8 +28,7 @@ import jax.numpy as jnp
 from repro.sim import engine, topology, workload
 from repro.sim.config import (BFC, DCQCN, DCTCP, FAIRQ, ORACLE, SFC,
                               SimConfig)
-from repro.sim.topology import (ClosParams, TopoDims, ideal_fct_ticks,
-                                routes_for_flows)
+from repro.sim.topology import ClosParams, TopoDims, ideal_fct_ticks
 from repro.sim.trace import EMIT_BASE, TraceSpec, layout
 from repro.sim.workload import FlowSet
 
@@ -62,13 +61,14 @@ def _incast_flowset(topo, n: int, size_pkts: int = 1 << 20) -> FlowSet:
     src = np.arange(1, n + 1, dtype=np.int32)
     dst = np.zeros(n, np.int32)
     sizes = np.full(n, size_pkts, np.int32)
-    routes = routes_for_flows(topo, src, dst, np.zeros(n, np.int64))
+    fid = np.arange(1, n + 1, dtype=np.int32)
+    routes = topo.routes(src, dst, fid)
     return FlowSet(src=src, dst=dst, size_pkts=sizes,
                    arrival_tick=np.zeros(n, np.int32), routes=routes,
                    ideal_fct=ideal_fct_ticks(
                        routes, sizes.astype(np.int64),
                        topo.params.prop_ticks).astype(np.int32),
-                   fid=np.arange(1, n + 1, dtype=np.int32),
+                   fid=fid,
                    is_incast=np.zeros(n, bool), horizon=1)
 
 

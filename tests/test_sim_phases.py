@@ -97,6 +97,44 @@ def test_control_pops_resume_ring_at_tau():
     assert int(np.asarray(ctx.bloom_counts).sum()) == 0  # filter cleaned
 
 
+def _pending_resumes(st, ops, flows, q_of):
+    """A state whose resume rings hold every multi-hop flow, paused at its
+    second hop in queue `q_of(f)` of that port."""
+    routes = np.asarray(flows.routes)
+    fs = [f for f in range(len(routes)) if (routes[f] >= 0).sum() >= 2]
+    f_ar = jnp.asarray(fs)
+    p_ar = jnp.asarray(routes[fs, 1])
+    q_ar = jnp.asarray([q_of(f) for f in fs])
+    counts = bloom.add_batch(st.bloom_counts, jnp.asarray(routes[fs, 0]),
+                             ops.fpos[f_ar], jnp.ones(len(fs), jnp.int32))
+    st = st._replace(
+        f_paused=st.f_paused.at[f_ar, 1].set(True),
+        f_q=st.f_q.at[f_ar, 1].set(q_ar),
+        f_cnt=st.f_cnt.at[f_ar, 1].set(1),
+        pl=st.pl.at[p_ar, q_ar, 0].set(f_ar),
+        pl_tail=st.pl_tail.at[p_ar, q_ar].set(1),
+        bloom_counts=counts)
+    return st, fs
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_control_pops_every_queue_whatever_the_chunk(monkeypatch, chunk):
+    """With more popping queues than one pass takes, the passes pop them
+    all, and the result is the single pass's bit for bit."""
+    env, st, ops, tops, topo, flows = _setup()
+    st, fs = _pending_resumes(st, ops, flows, lambda f: f % env.Q)
+    assert len(fs) > chunk
+    whole = _through(env, st, ops, tops, upto=1)
+    monkeypatch.setattr(importlib.import_module("repro.sim.phases.control"),
+                        "RESUME_CHUNK", chunk)
+    ctx = _through(env, st, ops, tops, upto=1)
+    for name in ("pl_head", "f_paused", "bloom_counts"):
+        assert np.array_equal(np.asarray(getattr(ctx, name)),
+                              np.asarray(getattr(whole, name))), name
+    assert not np.asarray(ctx.f_paused)[fs, 1].any()
+    assert int(np.asarray(ctx.bloom_counts).sum()) == 0
+
+
 def test_control_skips_resume_pop_between_tau_boundaries():
     """Off a tau boundary no queue may pop (`resume_limit`), so the resume
     block is skipped and leaves its state untouched bit for bit."""
@@ -221,7 +259,7 @@ def test_stats_assembles_next_state_and_emit():
 def test_stats_masks_phantom_ports_and_switches():
     dims = TopoDims(n_ports=CLOS.n_servers + 2 * 12 + 2 * 2 + 7,
                     n_servers=CLOS.n_servers + 3,
-                    n_switches=6, prop_max=CLOS.prop_ticks)
+                    n_switches=6, prop_max=CLOS.prop_ticks, hops=4)
     env, st, ops, tops, topo, flows = _setup(dims=dims)
     ctx = _through(env, st, ops, tops, upto=5)
     new_st, _ = phases.stats(env, st, ops, tops, ctx)

@@ -48,7 +48,7 @@ def test_padded_topology_bit_identical_serial():
     dims = TopoDims.of(topo)
     big = TopoDims(n_ports=dims.n_ports + 9, n_servers=dims.n_servers + 4,
                    n_switches=dims.n_switches + 3,
-                   prop_max=dims.prop_max)
+                   prop_max=dims.prop_max, hops=dims.hops)
 
     go = engine.compiled_runner(big, engine.static_cfg(cfg), flows.n_flows,
                                 n_ticks)

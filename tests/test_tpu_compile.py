@@ -66,6 +66,7 @@ def _sds(shape, dtype, sharding):
     ("drr", 384, None), ("srf", 384, None),      # the paper fabric's ports
     ("drr", 98, None), ("srf", 98, None),        # ragged: padded to a block
     ("drr", 384, 4), ("srf", 384, 4),            # a sweep chunk's vmap
+    ("drr", 768, None),                          # the k = 8 fat-tree's ports
 ])
 def test_bfc_fused_compiles_for_v5e(one_chip, scheduler, p, lanes):
     q = 32
