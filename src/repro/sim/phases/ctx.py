@@ -307,9 +307,7 @@ def derive(env: PhaseEnv, st, ops, topo) -> StepCtx:
     after every dequeue"), the dynamic per-queue pause threshold, PFC
     hysteresis, and this tick's flow arrivals at the sources."""
     pc, tm = env.cfg.proto, env.cfg.timing
-    P, Q, S, CAP = env.P, env.Q, env.S, env.CAP
-    p_ar = jnp.arange(P)
-    s_ar = jnp.arange(S)
+    P, Q, CAP = env.P, env.Q, env.CAP
 
     t = st.t
     occ = st.qtail - st.qhead                          # (P, Q)
@@ -323,9 +321,8 @@ def derive(env: PhaseEnv, st, ops, topo) -> StepCtx:
     head_f = jnp.maximum(head_entry >> 1, 0)
     if pc.backpressure:
         head_pos = ops.fpos[head_f]                             # (P, Q, S)
-        got = st.bloom_rx[p_ar[:, None, None], s_ar[None, None, :],
-                          head_pos]                             # (P, Q, S)
-        qpaused = got.all(axis=-1) & (occ > 0)
+        with jax.named_scope("derive.head_pause"):
+            qpaused = bloom.check(st.bloom_rx[:, None], head_pos) & (occ > 0)
     else:
         qpaused = jnp.zeros((P, Q), bool)
 

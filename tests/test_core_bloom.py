@@ -1,4 +1,5 @@
 """Bloom filter unit + property tests (paper §3.3.2, Fig. 8)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -96,3 +97,38 @@ def test_false_positive_rate_small():
     got = bloom.check(snap[None].repeat(len(probes), 0), _pos(probes))
     fp = float(jnp.mean(got.astype(jnp.float32)))
     assert fp < 5e-3, fp
+
+
+def _gather_check(bits, pos):
+    """Element-gather reference: bit pos[..., s] of stage s, in every stage."""
+    bits, pos = np.broadcast_arrays(np.asarray(bits),
+                                    np.asarray(pos)[..., None])
+    got = np.take_along_axis(bits, pos[..., :1], axis=-1)[..., 0]
+    return got.all(axis=-1)
+
+
+@pytest.mark.parametrize("lanes", [None, 3], ids=["one", "vmap"])
+@pytest.mark.parametrize("n_stages", [1, 4])
+@pytest.mark.parametrize("stage_bits", [64, 100, 256, 1024])
+def test_check_equals_element_gather(stage_bits, n_stages, lanes):
+    """Queue heads (P, Q, S) against their port's snapshot row broadcast as
+    (P, 1, S, B): the dense test equals an element gather, first and last
+    bit of a stage included, alone and vmapped over a lane axis."""
+    P, Q = 6, 5
+    lead = (lanes,) if lanes else ()
+    rng = np.random.default_rng(stage_bits * 10 + n_stages)
+    bits = rng.random(lead + (P, n_stages, stage_bits)) < 0.7
+    pos = rng.integers(0, stage_bits, lead + (P, Q, n_stages), dtype=np.int32)
+    pos[..., 0, :] = 0                    # every port's queue 0 reads bit 0
+    pos[..., 1, :] = stage_bits - 1       # ... and queue 1 the last bit
+    bits[..., 0, :, 0] = True             # set on port 0 in every stage
+    bits[..., 1, :, stage_bits - 1] = False   # clear on port 1
+    check = lambda b, p: bloom.check(b[:, None], p)   # noqa: E731
+    if lanes:
+        check = jax.vmap(check)
+    got = np.asarray(check(jnp.asarray(bits), jnp.asarray(pos)))
+    want = _gather_check(bits[..., :, None, :, :], pos)
+    assert got.shape == lead + (P, Q)
+    assert np.array_equal(got, want)
+    assert want[..., 0, 0].all() and not want[..., 1, 1].any()
+    assert want.sum() > want[..., 0, 0].size   # hits beyond the planted one

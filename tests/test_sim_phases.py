@@ -70,6 +70,35 @@ def test_derive_initial_tick():
     assert np.array_equal(np.asarray(ctx.rem_src), want)
 
 
+def test_derive_head_pause_bits_equal_the_element_gather():
+    """Mid-run, with pause bits landed and queues paused, derive's dense
+    head-of-queue test equals the per-element gather into each queue's own
+    port row of the received snapshot."""
+    topo = topology.build(CLOS)
+    cfg = SimConfig(proto=BFC, clos=CLOS)
+    flows = workload.generate(topo, workload.WorkloadParams(
+        workload="uniform", load=0.9, incast_load=0.3, incast_degree=6,
+        incast_total_kb=600, seed=5), 24)
+    st, _ = engine.run(topo, flows, cfg, 300, early_exit=False)
+    st = jax.tree.map(jnp.asarray, st)
+    dims = TopoDims.of(topo)
+    env = phases.make_env(dims, engine.static_cfg(cfg), flows.n_flows)
+    ops = engine.pack_flows(flows, cfg)
+    tops = pack_topo(topo, infinite_buffer=BFC.infinite_buffer, dims=dims)
+    ctx = phases.derive(env, st, ops, tops)
+
+    occ = np.asarray(st.qtail - st.qhead)
+    head = np.take_along_axis(np.asarray(st.qbuf),
+                              (np.asarray(st.qhead) % env.CAP)[..., None],
+                              axis=2)[..., 0]
+    head_pos = np.asarray(ops.fpos)[np.maximum(head >> 1, 0)]   # (P, Q, S)
+    rx = np.asarray(st.bloom_rx)
+    got = rx[np.arange(env.P)[:, None, None], np.arange(env.S), head_pos]
+    want = got.all(axis=-1) & (occ > 0)
+    assert rx.any() and want.any() and (occ > 0).sum() > want.sum()
+    assert np.array_equal(np.asarray(ctx.qpaused), want)
+
+
 def _pending_resume(st, ops, flows):
     """A state whose resume ring holds one paused, below-threshold flow."""
     routes = np.asarray(flows.routes)
